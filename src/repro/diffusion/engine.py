@@ -22,15 +22,20 @@ Data-parallel mesh mode (DESIGN.md §6): pass ``mesh`` (a
 ``repro.launch.mesh.make_elastic_mesh`` / ``make_smoke_mesh`` /
 ``make_data_mesh``) and the engine replicates the UNet/text/VAE parameters
 across the mesh while sharding prompt tokens and latents along the data
-axes.  The executable cache is keyed on the mesh signature, so an elastic
-relaunch onto a different mesh (``place_on_mesh``) retraces instead of
-reusing stale executables.  The stacked stats pytree comes back with its
+axes.  Calls run under ``jax.set_mesh(mesh)``, so the Pallas kernels —
+which the compiler cannot partition — run per data shard
+(``kernels.runtime.data_parallel``).  The executable cache is keyed on
+the mesh signature, so an elastic relaunch onto a different mesh
+(``place_on_mesh``) retraces instead of reusing stale executables.  The
+stacked stats pytree comes back with its
 per-row leaves still batch-sharded; only the scalar ledger counters are
 pulled to host, once, when the energy report reads them.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -246,16 +251,27 @@ class DiffusionEngine:
         return jax.device_put(x, self._data_sharding)
 
     # ------------------------------------------------------------------
-    def _run(self, prompt_tokens, uncond_tokens, latents, stats_rows=None,
-             sampler_policy=None, sampler_bank=None, policy_id=None):
+    def _weights(self) -> tuple:
+        """(text, denoiser, VAE) params: an argument of every executable.
+
+        Weights captured by a jitted closure would be embedded in the
+        program as constants — at full width 2.5 GB copied into every
+        executable, which exhausts host memory while compiling.
+        """
+        return self.text_params, self.unet_params, self.vae_params
+
+    def _run(self, weights, prompt_tokens, uncond_tokens, latents,
+             stats_rows=None, sampler_policy=None, sampler_bank=None,
+             policy_id=None):
         """Traced end-to-end path; ``uncond_tokens`` may be None (static)."""
         cfg = self.cfg
-        context = encode_text(self.text_params, prompt_tokens, cfg.text)
-        uncond = (encode_text(self.text_params, uncond_tokens, cfg.text)
+        text_params, unet_params, vae_params = weights
+        context = encode_text(text_params, prompt_tokens, cfg.text)
+        uncond = (encode_text(text_params, uncond_tokens, cfg.text)
                   if uncond_tokens is not None else None)
 
         def unet_apply(lat, tvec, ctx, active, **kw):
-            return self.denoiser.apply(self.unet_params, lat, tvec, ctx,
+            return self.denoiser.apply(unet_params, lat, tvec, ctx,
                                        tips_active=active, **kw)
 
         if cfg.unet.reuse_policy.enabled:
@@ -273,7 +289,7 @@ class DiffusionEngine:
                                          sampler_policy=sampler_policy,
                                          sampler_bank=sampler_bank,
                                          policy_id=policy_id)
-        images = decode(self.vae_params, latents, cfg.vae)
+        images = decode(vae_params, latents, cfg.vae)
         return images, latents, stats
 
     def set_precision(self, policy) -> "DiffusionEngine":
@@ -341,26 +357,26 @@ class DiffusionEngine:
             # contraction, breaking the bit-exact oracle contract
             if use_cfg and sampler_bank is not None:
                 fn = jax.jit(
-                    lambda p, u, l, pid: self._run(p, u, l, stats_rows,
+                    lambda w, p, u, l, pid: self._run(w, p, u, l, stats_rows,
+                                                      sampler_policy,
+                                                      sampler_bank, pid),
+                    donate_argnums=(3,))
+            elif use_cfg:
+                fn = jax.jit(
+                    lambda w, p, u, l: self._run(w, p, u, l, stats_rows,
+                                                 sampler_policy),
+                    donate_argnums=(3,))
+            elif sampler_bank is not None:
+                fn = jax.jit(
+                    lambda w, p, l, pid: self._run(w, p, None, l, stats_rows,
                                                    sampler_policy,
                                                    sampler_bank, pid),
                     donate_argnums=(2,))
-            elif use_cfg:
-                fn = jax.jit(
-                    lambda p, u, l: self._run(p, u, l, stats_rows,
-                                              sampler_policy),
-                    donate_argnums=(2,))
-            elif sampler_bank is not None:
-                fn = jax.jit(
-                    lambda p, l, pid: self._run(p, None, l, stats_rows,
-                                                sampler_policy,
-                                                sampler_bank, pid),
-                    donate_argnums=(1,))
             else:
                 fn = jax.jit(
-                    lambda p, l: self._run(p, None, l, stats_rows,
-                                           sampler_policy),
-                    donate_argnums=(1,))
+                    lambda w, p, l: self._run(w, p, None, l, stats_rows,
+                                              sampler_policy),
+                    donate_argnums=(2,))
             self._compiled[key] = fn
         return fn
 
@@ -424,20 +440,15 @@ class DiffusionEngine:
         latents = self._shard_batch(latents)
         fn = self._get_compiled(batch, use_cfg, stats_rows, sampler_policy,
                                 sampler_bank)
+        args = ((prompt_tokens, uncond_tokens, latents) if use_cfg
+                else (prompt_tokens, latents))
         if sampler_bank is not None:
-            pid = jnp.full((batch,), sampler_bank.index(sampler_policy),
-                           jnp.int32)
+            args += (jnp.full((batch,), sampler_bank.index(sampler_policy),
+                              jnp.int32),)
         t0 = time.perf_counter()
-        if use_cfg and sampler_bank is not None:
-            images, latents, stats = fn(prompt_tokens, uncond_tokens,
-                                        latents, pid)
-        elif use_cfg:
-            images, latents, stats = fn(prompt_tokens, uncond_tokens,
-                                        latents)
-        elif sampler_bank is not None:
-            images, latents, stats = fn(prompt_tokens, latents, pid)
-        else:
-            images, latents, stats = fn(prompt_tokens, latents)
+        with (jax.set_mesh(self.mesh) if self.mesh is not None
+              else contextlib.nullcontext()):
+            images, latents, stats = fn(self._weights(), *args)
         jax.block_until_ready(images)
         self.last_wall_s = time.perf_counter() - t0
         return EngineOutput(images=images, latents=latents, stats=stats)
@@ -540,9 +551,8 @@ class DiffusionEngine:
     def _encode_compiled(self):
         if self._encode_fn is None:
             self._encode_fn = jax.jit(
-                lambda toks: encode_text(self.text_params, toks,
-                                         self.cfg.text))
-        return self._encode_fn
+                lambda tp, toks: encode_text(tp, toks, self.cfg.text))
+        return functools.partial(self._encode_fn, self.text_params)
 
     def admit(self, state: SlotState, slot: int, prompt_tokens, key,
               uncond_tokens=None, latents=None,
@@ -615,11 +625,11 @@ class DiffusionEngine:
         return self._admit_fn(state, jnp.int32(slot), ctx[0], latents[0],
                               un_row, jnp.int32(policy_index))
 
-    def _slot_step_traced(self, state: SlotState) -> SlotState:
+    def _slot_step_traced(self, unet_params, state: SlotState) -> SlotState:
         cfg = self.cfg
 
         def unet_apply(lat, tvec, ctx, act, **kw):
-            return self.denoiser.apply(self.unet_params, lat, tvec, ctx,
+            return self.denoiser.apply(unet_params, lat, tvec, ctx,
                                        tips_active=act, **kw)
 
         if state.bank is not None:
@@ -674,10 +684,10 @@ class DiffusionEngine:
                self._policy_key(None, state.bank))
         fn = self._slot_compiled.get(key)
         if fn is None:
-            fn = jax.jit(self._slot_step_traced, donate_argnums=(0,))
+            fn = jax.jit(self._slot_step_traced, donate_argnums=(1,))
             self._slot_compiled[key] = fn
         t0 = time.perf_counter()
-        state = fn(state)
+        state = fn(self.unet_params, state)
         jax.block_until_ready(state.latents)
         self.last_wall_s = time.perf_counter() - t0
         return state
@@ -714,9 +724,9 @@ class DiffusionEngine:
         """
         if self._decode_fn is None:
             self._decode_fn = jax.jit(
-                lambda lat: decode(self.vae_params, lat, self.cfg.vae))
+                lambda vp, lat: decode(vp, lat, self.cfg.vae))
         if slots is None:
-            return self._decode_fn(state.latents)
+            return self._decode_fn(self.vae_params, state.latents)
         # power-of-two chunking bounds the executable count to log2(S)+1
         # while keeping retirement decodes near the per-row optimum; a
         # scheduler warms those sizes off the clock (see
@@ -731,7 +741,7 @@ class DiffusionEngine:
         while i < len(slots):
             c = 1 << ((len(slots) - i).bit_length() - 1)
             sel = jnp.asarray(slots[i:i + c], jnp.int32)
-            out.append(self._decode_fn(state.latents[sel]))
+            out.append(self._decode_fn(self.vae_params, state.latents[sel]))
             i += c
         return out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
 

@@ -11,6 +11,11 @@ Rows flagged low-precision (TIPS INT6) live on a 64x-coarser grid, so their
 skip is expressed by masking the ``lo`` operand with the precision flag, and
 the energy model credits the skipped slice (energy.MAC_PJ['int6x8']).
 
+Both slices lie in [0, 63] and the weights are signed INT8, so the wrapper
+narrows every operand to int8 (after the precision mask) and the kernel runs
+int8 x int8 -> int32 MXU matmuls: exact, and the only integer matmul the
+TPU's MXU has (it rejects int32 x int32).
+
 TPU mapping of the DBSC's dual *stationary* modes: both keep the full-K
 stripe of the stationary operand resident in VMEM and sweep the other operand
 with the innermost grid axis, so the stationary block's index map is constant
@@ -23,8 +28,8 @@ along the sweep (true reuse, no re-fetch):
 
 Each output block is visited exactly once (K is unrolled inside the kernel
 with a fori_loop over bk-wide slabs), so there is no cross-iteration
-accumulator hazard.  VMEM bound: (bm + bn) * K ints — with int8/int7 operand
-storage on real TPU this is K <= 16k at 128-wide blocks.
+accumulator hazard.  VMEM bound: (bm + bn) * K int8 operands — K <= 16k at
+128-wide blocks.
 """
 from __future__ import annotations
 
@@ -37,14 +42,14 @@ from jax.experimental import pallas as pl
 from repro.kernels.runtime import resolve_interpret
 
 
-def _kernel(x_hi_ref, x_lo_ref, w_ref, prec_ref, o_ref, *, bk: int):
+def _kernel(x_hi_ref, x_lo_ref, w_ref, o_ref, *, bk: int):
     kdim = x_hi_ref.shape[-1]
     nsteps = kdim // bk
 
     def body(s, acc):
         sl = pl.dslice(s * bk, bk)
         hi = x_hi_ref[:, sl]
-        lo = x_lo_ref[:, sl] * prec_ref[...]   # low slice skipped (INT6 rows)
+        lo = x_lo_ref[:, sl]           # zero on INT6 rows (masked on entry)
         w = w_ref[sl, :]
         acc_hi = jnp.dot(hi, w, preferred_element_type=jnp.int32)
         acc_lo = jnp.dot(lo, w, preferred_element_type=jnp.int32)
@@ -62,7 +67,12 @@ def bitslice_matmul_kernel(x_hi: jax.Array, x_lo: jax.Array, w: jax.Array,
                            bm: int = 128, bn: int = 128, bk: int = 128,
                            dataflow: str = "weight_stationary",
                            interpret: bool | None = None) -> jax.Array:
-    """int32 bit-planes (M,K), weights (K,N), precision flags (M,1) -> (M,N)."""
+    """int32 bit-planes (M,K), weights (K,N), precision flags (M,1) -> (M,N).
+
+    ``prec`` 0 rows drop their low slice before the kernel; every operand
+    then enters the kernel as int8 (exact for [0, 63] slices and INT8
+    weights) and the (M, N) accumulators are int32.
+    """
     m, kdim = x_hi.shape
     _, n = w.shape
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, (m, n, kdim)
@@ -72,14 +82,12 @@ def bitslice_matmul_kernel(x_hi: jax.Array, x_lo: jax.Array, w: jax.Array,
         grid = (n // bn, m // bm)
         xmap = lambda j, i: (i, 0)
         wmap = lambda j, i: (0, j)      # constant along the inner sweep
-        pmap_ = lambda j, i: (i, 0)
         omap = lambda j, i: (i, j)
     elif dataflow == "input_stationary":
         # CNN mode: activation stripe pinned, N innermost.
         grid = (m // bm, n // bn)
         xmap = lambda i, j: (i, 0)      # constant along the inner sweep
         wmap = lambda i, j: (0, j)
-        pmap_ = lambda i, j: (i, 0)
         omap = lambda i, j: (i, j)
     else:
         raise ValueError(dataflow)
@@ -91,9 +99,9 @@ def bitslice_matmul_kernel(x_hi: jax.Array, x_lo: jax.Array, w: jax.Array,
             pl.BlockSpec((bm, kdim), xmap),
             pl.BlockSpec((bm, kdim), xmap),
             pl.BlockSpec((kdim, bn), wmap),
-            pl.BlockSpec((bm, 1), pmap_),
         ],
         out_specs=pl.BlockSpec((bm, bn), omap),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=resolve_interpret(interpret),
-    )(x_hi, x_lo, w, prec)
+    )(x_hi.astype(jnp.int8), (x_lo * prec).astype(jnp.int8),
+      w.astype(jnp.int8))

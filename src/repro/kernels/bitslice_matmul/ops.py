@@ -16,6 +16,7 @@ from repro.core import quant
 from repro.kernels.bitslice_matmul.kernel import bitslice_matmul_kernel
 from repro.kernels.bitslice_matmul.ref import (bitslice_matmul_int8,
                                                bitslice_matmul_ref)
+from repro.kernels.runtime import data_parallel, data_shards
 
 
 def _pad_to(x, mult, axis):
@@ -65,14 +66,15 @@ def bitslice_matmul(x: jax.Array, w: jax.Array,
         acc = bitslice_matmul_int8(hi, lo, qw.values, prec)
     elif use_kernel:
         bm = bn = bk = 128
-        hi_p = _pad_to(_pad_to(hi, bm, 0), bk, 1)
-        lo_p = _pad_to(_pad_to(lo, bm, 0), bk, 1)
+        rows = bm * data_shards()       # every data shard gets whole blocks
+        hi_p = _pad_to(_pad_to(hi, rows, 0), bk, 1)
+        lo_p = _pad_to(_pad_to(lo, rows, 0), bk, 1)
         w_p = _pad_to(_pad_to(qw.values, bk, 0), bn, 1)
-        prec_p = _pad_to(prec, bm, 0)
-        acc = bitslice_matmul_kernel(hi_p, lo_p, w_p, prec_p,
-                                     bm=bm, bn=bn, bk=bk,
-                                     dataflow=dataflow,
-                                     interpret=interpret)[:m, :n]
+        prec_p = _pad_to(prec, rows, 0)
+        kernel = lambda h, lo_, p, w_: bitslice_matmul_kernel(
+            h, lo_, w_, p, bm=bm, bn=bn, bk=bk, dataflow=dataflow,
+            interpret=interpret)
+        acc = data_parallel(kernel, (hi_p, lo_p, prec_p), (w_p,))[:m, :n]
     else:
         acc = bitslice_matmul_ref(hi, lo, qw.values, prec)
     return acc.astype(jnp.float32) * (qx.scale * qw.scale)

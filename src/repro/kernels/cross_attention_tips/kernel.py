@@ -5,7 +5,9 @@ attention output, the per-query CLS attention score (CAS) — the quantity the
 IPSU thresholds to spot prompt-tied pixels.  The reference implementation
 materializes the full (B, H, Tq, Tk) probability tensor just to read its
 CLS column; here the probabilities only ever exist one (bq, Tk) block at a
-time in VMEM, and the CAS rides out as a (BH, Tq) side output.
+time in VMEM, and the CAS rides out as a side output — lane-dense (BH, 1, Tq)
+in (1, 1, bq) blocks, the layout the TPU's (8, 128) tiling accepts,
+reshaped to (BH, Tq) by the wrapper.
 
 Unlike the PSSA self-attention kernel, the key extent is the TEXT length
 (77 for CLIP, single digits at smoke geometry) — the whole K/V stripe of
@@ -68,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, cas_ref, *, sm_denom: float,
     p = e / jnp.sum(e, axis=-1, keepdims=True)    # (1, bq, tk) probs block
     o_ref[...] = jax.lax.dot_general(
         p, v, _PV_DIMS, preferred_element_type=jnp.float32)
-    cas_ref[...] = p[:, :, cls_index]
+    cas_ref[...] = p[:, :, cls_index].reshape(cas_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("cls_index", "bq", "interpret",
@@ -106,12 +108,13 @@ def cross_attention_tips_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tq, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(q, k, v)
-    return tuple(res)
+    out, cas = res
+    return out, cas.reshape(bh, tq)

@@ -16,7 +16,7 @@ import jax
 from repro.kernels.cross_attention_tips.kernel import (
     cross_attention_tips_kernel)
 from repro.kernels.cross_attention_tips.ref import cross_attention_tips_ref
-from repro.kernels.runtime import pad_axis_to
+from repro.kernels.runtime import data_parallel, pad_axis_to
 
 # text keys are sublane-padded to this multiple (77 -> 80; any Tk is legal)
 _KV_PAD = 8
@@ -42,10 +42,12 @@ def cross_attention_cas(q: jax.Array, k: jax.Array, v: jax.Array,
     qf, kf, vf = fold(q), fold(k), fold(v)
     if use_kernel:
         blk_q = min(bq, tq)
-        out, cas = cross_attention_tips_kernel(
-            pad_axis_to(qf, blk_q, 1), pad_axis_to(kf, _KV_PAD, 1),
-            pad_axis_to(vf, _KV_PAD, 1), cls_index=cls_index, bq=blk_q,
+        kernel = functools.partial(
+            cross_attention_tips_kernel, cls_index=cls_index, bq=blk_q,
             interpret=interpret, kv_len=tk)
+        out, cas = data_parallel(kernel, (pad_axis_to(qf, blk_q, 1),
+                                          pad_axis_to(kf, _KV_PAD, 1),
+                                          pad_axis_to(vf, _KV_PAD, 1)))
         out, cas = out[:, :tq], cas[:, :tq]        # drop padded query rows
     else:
         out, cas = cross_attention_tips_ref(qf, kf, vf, cls_index)

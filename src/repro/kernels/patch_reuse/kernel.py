@@ -9,7 +9,10 @@ is MXU/VPU-friendly (last dim is the wide one).
 
 The wrapper (``ops.py``) pads the patch axis to the block multiple with
 zeros on BOTH operands — padded patches read delta 0 and are sliced off,
-so padding is exact.
+so padding is exact.  The delta leaves the kernel as (B, P, 1) in
+(1, bp, 1) blocks: a row-wise max lands one value per sublane, and a
+trailing unit dimension is a block shape the TPU's (8, 128) tiling accepts
+(``bp`` a multiple of 8, or the whole padded patch axis).
 """
 from __future__ import annotations
 
@@ -24,7 +27,7 @@ from repro.kernels.runtime import resolve_interpret
 
 def _kernel(x_ref, r_ref, o_ref):
     d = jnp.abs(x_ref[0].astype(jnp.float32) - r_ref[0].astype(jnp.float32))
-    o_ref[0] = jnp.max(d, axis=-1)
+    o_ref[0] = jnp.max(d, axis=-1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bp", "interpret"))
@@ -44,7 +47,7 @@ def patch_delta_kernel(xf: jax.Array, rf: jax.Array, bp: int = 8,
             pl.BlockSpec((1, bp, w), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, bp, w), lambda i, j: (i, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bp), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, p), jnp.float32),
+        out_specs=pl.BlockSpec((1, bp, 1), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, p, 1), jnp.float32),
         interpret=resolve_interpret(interpret),
-    )(xf, rf)
+    )(xf, rf).reshape(b, p)

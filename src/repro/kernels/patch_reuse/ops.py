@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.kernels.patch_reuse.kernel import patch_delta_kernel
 from repro.kernels.patch_reuse.ref import patch_delta_ref
-from repro.kernels.runtime import pad_axis_to
+from repro.kernels.runtime import data_parallel, pad_axis_to
 
 
 @functools.partial(jax.jit, static_argnames=("patch", "threshold",
@@ -46,8 +46,9 @@ def patch_delta(x: jax.Array, x_ref: jax.Array, patch: int,
         fold = lambda a: a.reshape(b, t // patch, patch * c)
         xf = pad_axis_to(fold(x), bp, 1)
         rf = pad_axis_to(fold(x_ref), bp, 1)
-        delta = patch_delta_kernel(xf, rf, bp=bp,
-                                   interpret=interpret)[:, :t // patch]
+        kernel = functools.partial(patch_delta_kernel, bp=bp,
+                                   interpret=interpret)
+        delta = data_parallel(kernel, (xf, rf))[:, :t // patch]
     else:
         delta = patch_delta_ref(x, x_ref, patch)
     return delta, delta >= threshold

@@ -14,11 +14,14 @@ max/sum, so the kernel is two-pass (FlashAttention-2 style):
   pass 1: stream K blocks, maintain running (m, l) per query row;
   pass 2: stream K blocks again, p = exp(s - m)/l, zero p < tau, accumulate
           p @ V, popcount(p >= tau), and — when ``patch`` is set — the
-          PSXU delta-bitmap popcount.  The XOR between horizontally-adjacent
-          bitmap patches crosses K-block boundaries, so the last patch of
-          each block rides the loop carry into the next iteration; the first
-          patch overall XORs against zeros, i.e. is counted verbatim,
-          matching ``core.pssa.patch_xor``.
+          PSXU delta-bitmap popcount.  Each block's bitmap is XOR'd against
+          itself rotated right by ``patch`` lanes, which pairs every patch
+          with its left neighbour; the lanes that wrap around (the block's
+          first patch) take the previous block's last patch instead, carried
+          from the previous iteration's rotation.  The first patch overall
+          XORs against zeros, i.e. is counted verbatim, matching
+          ``core.pssa.patch_xor``.  Rotation keeps every array at the
+          block's (bq, bk) shape, so no unaligned lane slice is needed.
 
 ``kv_len`` supports block-padded operands: key columns >= kv_len are masked
 to -inf before the softmax statistics and excluded from every counter, so
@@ -26,7 +29,9 @@ padding to the block multiple (see ops.py) is exact.
 
 Grid: (batch*heads, Tq/bq); the full K/V stripe of one (batch, head) lives
 in VMEM (T x d x 2 operands — <= 4 MB for T=4096, d=64, fp32; half that in
-bf16 on silicon).
+bf16 on silicon).  The per-query counters leave the kernel as lane-dense
+(BH, 1, Tq) arrays in (1, 1, bq) blocks, the layout the TPU's (8, 128)
+tiling accepts; the wrapper reshapes them to (BH, Tq).
 """
 from __future__ import annotations
 
@@ -35,6 +40,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
@@ -90,30 +96,29 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, nnz_ref, *rest, bk: int,
         nnz = nnz + jnp.sum(keep.astype(jnp.int32), axis=-1)
         if patch is None:
             return acc, nnz
-        # PSXU accounting: XOR each bitmap patch against its left neighbour
-        # (carried across blocks); patches past kv_len are padding.
-        npb = bk // patch
-        kb = keep.reshape(bq, npb, patch)
-        shifted = jnp.concatenate([prev[:, None, :], kb[:, :-1, :]], axis=1)
-        delta = jnp.logical_xor(kb, shifted)
-        if padded:
-            gidx = s * npb + jax.lax.broadcasted_iota(
-                jnp.int32, (1, npb, 1), 1)
-            delta = jnp.logical_and(delta, gidx < kv_len // patch)
-        xor_cnt = xor_cnt + jnp.sum(delta.astype(jnp.int32), axis=(1, 2))
-        return acc, nnz, xor_cnt, kb[:, -1, :]
+        # PSXU accounting: XOR each bitmap patch against its left neighbour.
+        # rolled[:, j] = bits[:, j - patch]; its first `patch` lanes wrap to
+        # this block's last patch and are replaced by the previous block's.
+        bits = keep.astype(jnp.int32)
+        rolled = bits if patch == bk else pltpu.roll(bits, patch, 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        delta = bits != jnp.where(lane < patch, prev, rolled)
+        if padded:                     # patches past kv_len are padding
+            delta = jnp.logical_and(delta, kv_valid(s))
+        xor_cnt = xor_cnt + jnp.sum(delta.astype(jnp.int32), axis=-1)
+        return acc, nnz, xor_cnt, rolled
 
     acc0 = jnp.zeros_like(o_ref[0])
     nnz0 = jnp.zeros((bq,), jnp.int32)
     if patch is None:
         acc, nnz = jax.lax.fori_loop(0, nk, pass2, (acc0, nnz0))
     else:
-        prev0 = jnp.zeros((bq, patch), jnp.bool_)
+        prev0 = jnp.zeros((bq, bk), jnp.int32)
         acc, nnz, xor_cnt, _ = jax.lax.fori_loop(
             0, nk, pass2, (acc0, nnz0, jnp.zeros((bq,), jnp.int32), prev0))
-        xor_ref[0] = xor_cnt
+        xor_ref[0] = xor_cnt.reshape(1, bq)
     o_ref[0] = acc
-    nnz_ref[0] = nnz
+    nnz_ref[0] = nnz.reshape(1, bq)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk", "threshold",
@@ -141,17 +146,15 @@ def pssa_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         assert bk % patch == 0 and kv_len % patch == 0, (bk, kv_len, patch)
     sm_scale = 1.0 / (d ** 0.5)
 
-    out_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, bq), lambda b, i: (b, i)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((bh, tq, d), jnp.float32),
-        jax.ShapeDtypeStruct((bh, tq), jnp.int32),
-    ]
+    counter_spec = pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i))
+    counter_shape = jax.ShapeDtypeStruct((bh, 1, tq), jnp.int32)
+    out_specs = [pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0)),
+                 counter_spec]
+    out_shape = [jax.ShapeDtypeStruct((bh, tq, d), jnp.float32),
+                 counter_shape]
     if patch is not None:
-        out_specs.append(pl.BlockSpec((1, bq), lambda b, i: (b, i)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, tq), jnp.int32))
+        out_specs.append(counter_spec)
+        out_shape.append(counter_shape)
 
     res = pl.pallas_call(
         functools.partial(_kernel, bk=bk, sm_scale=sm_scale,
@@ -166,4 +169,4 @@ def pssa_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
         out_shape=out_shape,
         interpret=resolve_interpret(interpret),
     )(q, k, v)
-    return tuple(res)
+    return (res[0],) + tuple(c.reshape(bh, tq) for c in res[1:])

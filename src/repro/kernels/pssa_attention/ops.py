@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from repro.kernels.pssa_attention.kernel import pssa_attention_kernel
 from repro.kernels.pssa_attention.ref import (pssa_attention_ref,
                                               pssa_attention_stats_ref)
-from repro.kernels.runtime import pad_axis_to
+from repro.kernels.runtime import data_parallel, pad_axis_to
 
 
 @functools.partial(jax.jit, static_argnames=("threshold", "patch",
@@ -49,10 +49,12 @@ def pssa_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         blk_k = min(bk, t)
         if patch is not None:
             blk_k = max(patch, blk_k - blk_k % patch)
-        res = pssa_attention_kernel(
-            pad_axis_to(qf, blk_q, 1), pad_axis_to(kf, blk_k, 1),
-            pad_axis_to(vf, blk_k, 1), threshold,
-            bq=blk_q, bk=blk_k, interpret=interpret, kv_len=t, patch=patch)
+        kernel = functools.partial(
+            pssa_attention_kernel, threshold=threshold, bq=blk_q, bk=blk_k,
+            interpret=interpret, kv_len=t, patch=patch)
+        res = data_parallel(kernel, (pad_axis_to(qf, blk_q, 1),
+                                     pad_axis_to(kf, blk_k, 1),
+                                     pad_axis_to(vf, blk_k, 1)))
         res = tuple(x[:, :t] for x in res)          # drop padded query rows
     elif patch is None:
         res = pssa_attention_ref(qf, kf, vf, threshold)

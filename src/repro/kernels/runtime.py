@@ -1,6 +1,6 @@
 """Shared runtime policy helpers for the Pallas op wrappers.
 
-Three concerns every ``ops.py`` wrapper (and the autotuner) has in common:
+Four concerns every ``ops.py`` wrapper (and the autotuner) has in common:
 
 * **interpret selection** — the kernels must run in Pallas interpret mode on
   CPU (the test/CI container) and compiled on a real accelerator.  The seed
@@ -13,6 +13,11 @@ Three concerns every ``ops.py`` wrapper (and the autotuner) has in common:
   blocks for non-power-of-two extents; ``pad_axis_to`` pads the operand up
   to the block multiple instead (callers slice the result back), matching
   what ``bitslice_matmul/ops.py`` always did.
+* **data-parallel meshes** — the compiler cannot partition a Mosaic
+  kernel over a sharded batch; ``data_parallel`` runs a kernel call per
+  data shard under the context mesh instead.  Which mesh axes carry data
+  (``DATA_AXES``, ``dp_axes_of``) is decided here, once, for the kernels
+  and for the launchers that build the meshes.
 * **min-of-k wall-clock** — the block autotuner (``kernels.autotune``) and
   every bench time jitted callables the same way: warm up outside the
   clock, then take the MINIMUM of k block-until-ready repetitions (one
@@ -21,10 +26,12 @@ Three concerns every ``ops.py`` wrapper (and the autotuner) has in common:
 """
 from __future__ import annotations
 
+import math
 import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 # backends with a real Pallas lowering: Mosaic on TPU, triton-pallas on
@@ -62,6 +69,45 @@ def pad_axis_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+# mesh axes that split the batch; every other axis (``model``) splits weights
+DATA_AXES = ("pod", "data")
+
+
+def dp_axes_of(mesh) -> tuple:
+    """The mesh's data axes, in mesh order."""
+    return tuple(a for a in mesh.axis_names if a in DATA_AXES)
+
+
+def dp_size_of(mesh) -> int:
+    """Total data-parallel degree (product of the data axis sizes)."""
+    return math.prod(int(mesh.shape[a]) for a in dp_axes_of(mesh))
+
+
+def data_shards() -> int:
+    """Devices the context mesh's data axes span (1 without a mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else dp_size_of(mesh)
+
+
+def data_parallel(fn, batched: tuple, replicated: tuple = ()):
+    """``fn(*batched, *replicated)``, split over the context mesh's data axes.
+
+    Mosaic kernels cannot be partitioned by the compiler, so under a mesh
+    set with ``jax.set_mesh`` whose data axes (``dp_axes_of``)
+    span several devices, ``fn`` runs per shard through ``jax.shard_map``:
+    the leading axis of every ``batched`` operand and of every result is
+    split over those axes, and ``replicated`` operands are whole on every
+    device.  The kernels are independent across their leading axis, so the
+    results equal one unsplit call.  Without such a mesh this is ``fn``.
+    """
+    if data_shards() == 1:
+        return fn(*batched, *replicated)
+    spec = P(dp_axes_of(jax.sharding.get_abstract_mesh()))
+    in_specs = (spec,) * len(batched) + (P(),) * len(replicated)
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=spec,
+                         check_vma=False)(*batched, *replicated)
 
 
 def timed(fn, *args, reps: int = 3, warmup: int = 1):

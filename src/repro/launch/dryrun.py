@@ -50,7 +50,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCH_NAMES, SHAPES, get_arch, shape_applicable
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.data import make_batch_specs
-from repro.launch.mesh import dp_axes_of, make_production_mesh, use_mesh
+from repro.launch.mesh import dp_axes_of, make_production_mesh
 from repro.models import transformer as T
 from repro.models.layers import ShardCtx
 from repro.optim import AdamW
@@ -283,7 +283,7 @@ def _compile_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
     # read+write copy of the cache per token)
     donate = (0,) if shape.kind == "train" else \
         ((1,) if shape.kind == "decode" else ())
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=shardings,
                           donate_argnums=donate).lower(*args)
         t_lower = time.perf_counter() - t0
@@ -307,7 +307,7 @@ def jaxpr_flops_cell(cfg: ArchConfig, shape: ShapeConfig, mesh) -> float:
     from repro.launch.flops import flops_of_callable
     args, _ = input_specs(cfg, shape, mesh)
     fn = step_callable(cfg, shape, mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return flops_of_callable(fn, *args)
 
 

@@ -1,27 +1,25 @@
-"""Mesh construction (production + elastic variants) and version compat.
+"""Mesh construction (production + elastic variants).
 
 All constructors are FUNCTIONS so importing this module never touches jax
-device state (the dry-run must set XLA_FLAGS before first jax init).
+device state (the dry-run must set XLA_FLAGS before first jax init).  Every
+mesh has Auto axes: the engine and the models annotate their shardings
+with ``NamedSharding`` and leave propagation to the compiler, which the
+Explicit axes ``jax.make_mesh`` defaults to would refuse (sharding-typed
+ops such as ``jnp.take`` on a sharded operand raise).
 """
 from __future__ import annotations
 
-import math
 import os
 
 import jax
 
+# the data-axis naming lives with the kernels that split over it
+from repro.kernels.runtime import dp_axes_of, dp_size_of  # noqa: F401
 
-def use_mesh(mesh):
-    """Version-portable "active mesh" context manager.
 
-    jax >= 0.6 exposes ``jax.set_mesh`` (usable as a context manager);
-    earlier versions (the container floor is 0.4.37) activate a mesh by
-    entering the ``Mesh`` object itself.  Everything in this repo annotates
-    shardings explicitly with ``NamedSharding``, which works under either —
-    the context only matters for code that resolves bare axis names.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def _auto_mesh(shape: tuple, axes: tuple):
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def simulate_host_devices(count: int) -> None:
@@ -71,16 +69,7 @@ def make_production_mesh(*, multi_pod: bool = False, tp_size: int = 16):
     dp = per_pod // tp_size
     shape = (2, dp, tp_size) if multi_pod else (dp, tp_size)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def dp_axes_of(mesh) -> tuple:
-    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-
-
-def dp_size_of(mesh) -> int:
-    """Total data-parallel degree (product of the pod/data axis sizes)."""
-    return math.prod(int(mesh.shape[a]) for a in dp_axes_of(mesh))
+    return _auto_mesh(shape, axes)
 
 
 def make_data_mesh(dp: int):
@@ -110,10 +99,10 @@ def make_elastic_mesh(tp_size: int = 16):
     tp = min(tp_size, n)
     while n % tp:
         tp -= 1
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return _auto_mesh((n // tp, tp), ("data", "model"))
 
 
 def make_smoke_mesh():
     """1x1 mesh on the single CPU device (tests exercise the sharded code
     paths without fake devices)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

@@ -398,6 +398,7 @@ def _main(argv=None) -> int:
     from repro.diffusion.engine import DiffusionEngine
     from repro.launch.cli import (add_policy_args, config_from_args,
                                   policies_from_args)
+    from repro.launch.platform import use_compile_cache
     from repro.launch.scheduler import (apply_trace, bursty_trace,
                                         make_requests)
 
@@ -417,6 +418,7 @@ def _main(argv=None) -> int:
     ap.add_argument("--check-identity", action="store_true",
                     help="assert ledger bit-identity 1 vs N replicas")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     policies = policies_from_args(args)
     cfg = config_from_args(args, policies=policies, steps=args.steps)

@@ -57,14 +57,14 @@ is bit-identical to the same requests served one-shot, at any slot count
 or occupancy (tests/test_continuous.py pins this).
 
 Mesh mode (``--mesh N``): data-parallel sharded execution over N devices
-(DESIGN.md §6).  On a CPU host the N devices are simulated with the
-dry-run's ``XLA_FLAGS`` trick (set before jax initializes); on TPU the
-first N real devices are used.  The scheduler rounds the micro-batch up to
-a multiple of the dp degree, shards prompt tokens and latents along the
-``data`` axis (params replicated), and masks padded tail rows out of every
-reported metric: ``stats_rows`` restricts the PSSA/TIPS accounting to the
-valid rows at the source, so the energy ledger never sees a padded
-duplicate.
+(DESIGN.md §6).  With ``JAX_PLATFORMS=cpu`` the N devices are simulated
+with the dry-run's ``XLA_FLAGS`` trick (set before jax initializes);
+otherwise the first N real devices are used.  The scheduler rounds the
+micro-batch up to a multiple of the dp degree, shards prompt tokens and
+latents along the ``data`` axis (params replicated), and masks padded tail
+rows out of every reported metric: ``stats_rows`` restricts the PSSA/TIPS
+accounting to the valid rows at the source, so the energy ledger never
+sees a padded duplicate.
 
 Reports aggregate imgs/s (valid images only), per-iteration wall time, and
 (with ``--ledger``) the full-geometry energy headline driven by the stats
@@ -93,7 +93,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import time
 
 
@@ -367,8 +366,9 @@ def main():
                     help="print the full-geometry energy headline")
     ap.add_argument("--mesh", type=int, default=0,
                     help="data-parallel degree: shard micro-batches over N "
-                         "devices (simulated host devices on CPU, real on "
-                         "TPU); 0 = single-device")
+                         "devices (simulated host devices when "
+                         "JAX_PLATFORMS=cpu, the first N real devices "
+                         "otherwise); 0 = single-device")
     ap.add_argument("--edit", action="store_true",
                     help="serve the img2img/editing request class (shared "
                          "base latent + localized per-request edits) — "
@@ -450,14 +450,14 @@ def main():
         ap.error("--edit traces share one base latent workload; tiered "
                  "admission is t2i-only for now")
 
-    if args.mesh > 1:
-        # must run before the first jax backend init; only meaningful for
-        # host (CPU) platforms — TPU/GPU expose their real devices
-        plat = (os.environ.get("JAX_PLATFORMS")
-                or os.environ.get("JAX_PLATFORM_NAME") or "cpu")
-        if "tpu" not in plat and "gpu" not in plat and "cuda" not in plat:
-            from repro.launch.mesh import simulate_host_devices
-            simulate_host_devices(args.mesh)
+    from repro.launch.platform import cpu_forced, use_compile_cache
+
+    if args.mesh > 1 and cpu_forced():
+        # must run before the first jax backend init; an accelerator host
+        # exposes its real devices
+        from repro.launch.mesh import simulate_host_devices
+        simulate_host_devices(args.mesh)
+    use_compile_cache()
 
     from repro.launch.cli import config_from_args, policies_from_args
     from repro.launch.mesh import make_data_mesh

@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models.layers import ShardCtx
@@ -163,12 +162,12 @@ def moe_ffn(x, p, cfg: ArchConfig, ctx: Optional[ShardCtx],
             aux = jax.lax.pmean(aux, dp)
             return y.reshape(xl.shape), aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(dp, None, None), specs["router"], specs["we_gate"],
                       specs["we_up"], specs["we_down"]),
             out_specs=(P(dp, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 
     if cfg.num_shared_experts:

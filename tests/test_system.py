@@ -118,11 +118,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_arch
-from repro.launch.mesh import use_mesh
 from repro.models import transformer as T
 from repro.models.layers import ShardCtx
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 # vanilla numerics: TIPS/PSSA fake-quant amplifies bf16 reduction-order
 # noise across shardings; exactness is only expected feature-off
 cfg = get_arch("%(arch)s").smoke().scaled(
@@ -136,8 +136,7 @@ ref, _, _ = T.forward(params, cfg, None, tokens=toks, remat=False)
 ctx = ShardCtx(mesh=mesh, dp_axes=("data",))
 specs = T.param_specs(cfg, 4)
 ns = lambda s: NamedSharding(mesh, s)
-# use_mesh: jax.set_mesh on jax >= 0.6, the Mesh context manager below it
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     psh = jax.tree.map(lambda s: ns(s), specs, is_leaf=lambda x: isinstance(x, P))
     sp = jax.device_put(params, psh)
     st = jax.device_put(toks, ns(P("data", None)))

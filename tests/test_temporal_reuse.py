@@ -38,6 +38,11 @@ from repro.kernels.dispatch import KernelPolicy
 from repro.kernels.patch_reuse import ops as reuse_ops
 from repro.kernels.patch_reuse.ref import patch_delta_ref
 
+# Relative L2 bound between the compiled dense and threshold-0 engine
+# images (smoke geometry, 3 guided steps, CPU): the sound reading is
+# 1.19e-4, a threshold that really reuses patches (1.0) reads 4.6e-3.
+THR0_COMPILED_REL_L2 = 1e-3
+
 
 @pytest.fixture(scope="module")
 def ucfg():
@@ -344,21 +349,38 @@ class TestEngine:
                                   cfg.text.vocab_size)
 
     def test_one_shot_thr0_bit_identical(self, cfg, toks):
+        """Threshold-0 reuse recomputes every patch: the dense result.
+
+        Op by op (``jax.disable_jit``) every operation of the gathered
+        path meets the same inputs as its dense twin: bit for bit.  The
+        engine's compiled programs take the weights as runtime operands,
+        and there the compiler fuses the gathered path's layer norms apart
+        from the dense path's, so the images differ by rounding: relative
+        L2 1.19e-4 on the CPU, against 4.6e-3 once threshold 1.0 really
+        reuses patches.  ``THR0_COMPILED_REL_L2`` sits between the two.
+        """
         un = jnp.zeros_like(toks)
         eng_d = DiffusionEngine(cfg, key=jax.random.PRNGKey(0))
         eng_r = DiffusionEngine(cfg, key=jax.random.PRNGKey(0),
                                 reuse_policy=ReusePolicy.temporal(
                                     threshold=0.0))
-        lat0 = eng_d.init_latents(2, jax.random.PRNGKey(7))
-        out_d = eng_d.generate(toks, None, uncond_tokens=un,
-                               latents=lat0)
-        out_r = eng_r.generate(toks, None, uncond_tokens=un,
-                               latents=eng_r.init_latents(
-                                   2, jax.random.PRNGKey(7)))
-        assert jnp.array_equal(out_d.images, out_r.images)
-        # dense trajectories report zero reuse
-        assert aggregated_reuse_ratios_per_iter(cfg, [out_d.stats]) \
-            == [0.0, 0.0, 0.0]
+
+        def generate(eng):
+            return eng.generate(toks, None, uncond_tokens=un,
+                                latents=eng.init_latents(
+                                    2, jax.random.PRNGKey(7)))
+
+        out_d, out_r = generate(eng_d), generate(eng_r)
+        rel = float(jnp.linalg.norm(out_r.images - out_d.images)
+                    / jnp.linalg.norm(out_d.images))
+        assert rel <= THR0_COMPILED_REL_L2, rel
+        with jax.disable_jit():
+            eager_d, eager_r = generate(eng_d), generate(eng_r)
+        assert jnp.array_equal(eager_d.images, eager_r.images)
+        # dense trajectories report zero reuse, and so does threshold 0
+        for out in (out_d, out_r, eager_d, eager_r):
+            assert aggregated_reuse_ratios_per_iter(cfg, [out.stats]) \
+                == [0.0, 0.0, 0.0]
 
     def test_slot_parity_and_counters_across_slot_counts(self, cfg, toks):
         un = jnp.zeros_like(toks)
