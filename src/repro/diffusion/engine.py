@@ -41,6 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.policies import ServePolicies, legacy_warning
@@ -550,8 +551,11 @@ class DiffusionEngine:
 
     def _encode_compiled(self):
         if self._encode_fn is None:
-            self._encode_fn = jax.jit(
-                lambda tp, toks: encode_text(tp, toks, self.cfg.text))
+            # a named function, so the program reads ``jit_text_encode``
+            # in a profiler trace
+            def text_encode(tp, toks):
+                return encode_text(tp, toks, self.cfg.text)
+            self._encode_fn = jax.jit(text_encode)
         return functools.partial(self._encode_fn, self.text_params)
 
     def admit(self, state: SlotState, slot: int, prompt_tokens, key,
@@ -588,9 +592,20 @@ class DiffusionEngine:
                 f"policy_index={policy_index} outside the state's bank "
                 f"of {len(state.bank)} policies")
         enc = self._encode_compiled()
-        ctx = enc(prompt_tokens)
-        if latents is None:
-            latents = self.init_latents(1, key)
+        with TraceAnnotation("engine.admit"):
+            with TraceAnnotation("engine.encode"):
+                ctx = enc(prompt_tokens)
+            un = None
+            if use_cfg:
+                with TraceAnnotation("engine.encode"):
+                    un = enc(uncond_tokens)
+            if latents is None:
+                latents = self.init_latents(1, key)
+            return self._admit_compiled()(
+                state, jnp.int32(slot), ctx[0], latents[0],
+                None if un is None else un[0], jnp.int32(policy_index))
+
+    def _admit_compiled(self):
         if self._admit_fn is None:
             # one fused dispatch per admission (slot index and policy
             # traced, so any slot/policy reuses the same executable);
@@ -621,9 +636,7 @@ class DiffusionEngine:
                         solver_hist=state.solver_hist.at[slot].set(0.0))
                 return new
             self._admit_fn = jax.jit(_adm, donate_argnums=(0,))
-        un_row = enc(uncond_tokens)[0] if use_cfg else None
-        return self._admit_fn(state, jnp.int32(slot), ctx[0], latents[0],
-                              un_row, jnp.int32(policy_index))
+        return self._admit_fn
 
     def _slot_step_traced(self, unet_params, state: SlotState) -> SlotState:
         cfg = self.cfg
@@ -686,10 +699,12 @@ class DiffusionEngine:
         if fn is None:
             fn = jax.jit(self._slot_step_traced, donate_argnums=(1,))
             self._slot_compiled[key] = fn
-        t0 = time.perf_counter()
-        state = fn(self.unet_params, state)
-        jax.block_until_ready(state.latents)
-        self.last_wall_s = time.perf_counter() - t0
+        with TraceAnnotation("engine.slot_step"):
+            t0 = time.perf_counter()
+            state = fn(self.unet_params, state)
+            with TraceAnnotation("engine.step_wait"):
+                jax.block_until_ready(state.latents)
+            self.last_wall_s = time.perf_counter() - t0
         return state
 
     def finished_slots(self, state: SlotState) -> list:
@@ -699,15 +714,16 @@ class DiffusionEngine:
         budget — short-budget (draft-tier) rows retire early while
         quality-tier neighbours keep stepping.
         """
-        if state.bank is not None:
-            idx, act, pid = jax.device_get(
-                (state.step_idx, state.active, state.policy_id))
-            budgets = [p.num_steps for p in state.bank]
-            return [i for i in range(len(idx))
-                    if act[i] and idx[i] >= budgets[pid[i]]]
-        n = self.cfg.ddim.num_inference_steps
-        idx, act = jax.device_get((state.step_idx, state.active))
-        return [i for i in range(len(idx)) if act[i] and idx[i] >= n]
+        with TraceAnnotation("engine.finished_slots"):
+            if state.bank is not None:
+                idx, act, pid = jax.device_get(
+                    (state.step_idx, state.active, state.policy_id))
+                budgets = [p.num_steps for p in state.bank]
+                return [i for i in range(len(idx))
+                        if act[i] and idx[i] >= budgets[pid[i]]]
+            n = self.cfg.ddim.num_inference_steps
+            idx, act = jax.device_get((state.step_idx, state.active))
+            return [i for i in range(len(idx)) if act[i] and idx[i] >= n]
 
     def decode_slots(self, state: SlotState, slots=None) -> jax.Array:
         """VAE-decode slot latents through a cached executable.
@@ -723,10 +739,14 @@ class DiffusionEngine:
         choice is pure wall time.
         """
         if self._decode_fn is None:
-            self._decode_fn = jax.jit(
-                lambda vp, lat: decode(vp, lat, self.cfg.vae))
+            # a named function, so the program reads ``jit_vae_decode``
+            # in a profiler trace
+            def vae_decode(vp, lat):
+                return decode(vp, lat, self.cfg.vae)
+            self._decode_fn = jax.jit(vae_decode)
         if slots is None:
-            return self._decode_fn(self.vae_params, state.latents)
+            with TraceAnnotation("engine.decode"):
+                return self._decode_fn(self.vae_params, state.latents)
         # power-of-two chunking bounds the executable count to log2(S)+1
         # while keeping retirement decodes near the per-row optimum; a
         # scheduler warms those sizes off the clock (see
@@ -737,13 +757,15 @@ class DiffusionEngine:
                 "decode_slots: empty slot list — guard on "
                 "finished_slots() (or pass slots=None for the whole "
                 "buffer)")
-        out, i = [], 0
-        while i < len(slots):
-            c = 1 << ((len(slots) - i).bit_length() - 1)
-            sel = jnp.asarray(slots[i:i + c], jnp.int32)
-            out.append(self._decode_fn(self.vae_params, state.latents[sel]))
-            i += c
-        return out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
+        with TraceAnnotation("engine.decode"):
+            out, i = [], 0
+            while i < len(slots):
+                c = 1 << ((len(slots) - i).bit_length() - 1)
+                sel = jnp.asarray(slots[i:i + c], jnp.int32)
+                out.append(self._decode_fn(self.vae_params,
+                                           state.latents[sel]))
+                i += c
+            return out[0] if len(out) == 1 else jnp.concatenate(out, axis=0)
 
     def decode_preview(self, state: SlotState, slots) -> jax.Array:
         """Progressive preview decode of IN-FLIGHT slot latents.
@@ -762,6 +784,7 @@ class DiffusionEngine:
 
     def retire(self, state: SlotState, slots) -> SlotState:
         """Free finished slots (after decoding); rows become admissible."""
-        idx = jnp.asarray(list(slots), jnp.int32)
-        return dataclasses.replace(state,
-                                   active=state.active.at[idx].set(False))
+        with TraceAnnotation("engine.retire"):
+            idx = jnp.asarray(list(slots), jnp.int32)
+            return dataclasses.replace(
+                state, active=state.active.at[idx].set(False))

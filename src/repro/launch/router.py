@@ -40,6 +40,7 @@ to the same requests served one-shot.  Tests: tests/test_router.py.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Iterator, Optional
@@ -191,6 +192,7 @@ class ClusterRouter:
         a request.
         """
         import jax
+        from jax.profiler import TraceAnnotation
 
         if self.bank is None:
             for r in requests:
@@ -209,109 +211,125 @@ class ClusterRouter:
         round_idx = 0
         stepped_rows = 0
         step_calls = 0
-        step_wall = 0.0
         self._t0 = t0 = time.perf_counter()
         while completed < len(requests) or decode_jobs or preview_jobs:
             now = time.perf_counter() - t0
-            poll_arrivals(pending, ready, now)
-            for r in ready:
-                if r.arrival_round is None:
-                    r.arrival_round = round_idx
-            # FIFO admission, least-occupied replica first; degrade
-            # decision happens HERE, with the request's realized wait
-            while ready:
-                free = [(len(owners[i]), i) for i in range(self.replicas)
-                        if len(owners[i]) < self.slots_per_replica]
-                if not free:
-                    break
-                req = ready.pop(0)
-                _, ri = min(free)
-                slot = next(s for s in range(self.slots_per_replica)
-                            if s not in owners[ri])
-                pidx = self._admission_tier(req, round_idx)
-                if pidx != req.policy_index:
-                    req.degraded_from = req.tier
-                    req.policy_index = pidx
-                    req.tier = self.bank[pidx].label()
-                states[ri] = self.engines[ri].admit(
-                    states[ri], slot, req.tokens, None,
-                    uncond_tokens=req.uncond_tokens, latents=req.latents,
-                    policy_index=req.policy_index)
-                owners[ri][slot] = req
-                req.replica = ri
-                req.admitted_s = time.perf_counter() - t0
-                yield {"event": "admitted", "rid": req.rid, "replica": ri,
-                       "slot": slot, "round": round_idx,
-                       "tier": req.tier, "degraded_from": req.degraded_from,
-                       "t_s": req.admitted_s}
-            # fetch decodes dispatched LAST round — they computed while
-            # we admitted, so pixel movement never blocked admission
-            for req, fin_round, row in decode_jobs:
-                req.image = np.asarray(jax.device_get(row))[0]
-                req.finished_s = time.perf_counter() - t0
-                req.finish_round = fin_round
-                completed += 1
-                yield {"event": "finished", "rid": req.rid,
-                       "replica": req.replica, "round": fin_round,
-                       "tier": req.tier, "image": req.image,
-                       "t_s": req.finished_s}
-            decode_jobs = []
-            for req, at_step, row in preview_jobs:
-                img = np.asarray(jax.device_get(row))[0]
-                req.previews += 1
-                pv_t = time.perf_counter() - t0
-                if req.first_preview_s is None:
-                    req.first_preview_s = pv_t
-                yield {"event": "preview", "rid": req.rid,
-                       "replica": req.replica, "round": round_idx,
-                       "step": at_step, "image": img, "t_s": pv_t}
-            preview_jobs = []
-            if not any(owners):
-                if completed < len(requests) and pending:
-                    time.sleep(max(pending[0].arrival_s
-                                   - (time.perf_counter() - t0), 0.0))
-                continue
-            # one router round: step every occupied replica
-            for ri in range(self.replicas):
-                if not owners[ri]:
+            # a pass steps iff a slot is occupied or a request has arrived;
+            # only such a pass is a round.  Host spans (on the profiler's
+            # trace, free when it is off) inside a round close before every
+            # yield, so the consumer's time is the round's own
+            stepping = (bool(ready) or any(owners)
+                        or bool(pending and pending[0].arrival_s <= now))
+            with (TraceAnnotation("router.round") if stepping
+                  else contextlib.nullcontext()):
+                poll_arrivals(pending, ready, now)
+                for r in ready:
+                    if r.arrival_round is None:
+                        r.arrival_round = round_idx
+                # FIFO admission, least-occupied replica first; degrade
+                # decision happens HERE, with the request's realized wait
+                while ready:
+                    free = [(len(owners[i]), i)
+                            for i in range(self.replicas)
+                            if len(owners[i]) < self.slots_per_replica]
+                    if not free:
+                        break
+                    req = ready.pop(0)
+                    _, ri = min(free)
+                    slot = next(s for s in range(self.slots_per_replica)
+                                if s not in owners[ri])
+                    with TraceAnnotation("router.admit", rid=req.rid):
+                        pidx = self._admission_tier(req, round_idx)
+                        if pidx != req.policy_index:
+                            req.degraded_from = req.tier
+                            req.policy_index = pidx
+                            req.tier = self.bank[pidx].label()
+                        states[ri] = self.engines[ri].admit(
+                            states[ri], slot, req.tokens, None,
+                            uncond_tokens=req.uncond_tokens,
+                            latents=req.latents,
+                            policy_index=req.policy_index)
+                    owners[ri][slot] = req
+                    req.replica = ri
+                    req.admitted_s = time.perf_counter() - t0
+                    yield {"event": "admitted", "rid": req.rid,
+                           "replica": ri, "slot": slot, "round": round_idx,
+                           "tier": req.tier,
+                           "degraded_from": req.degraded_from,
+                           "t_s": req.admitted_s}
+                # fetch decodes dispatched LAST round — they computed while
+                # we admitted, so pixel movement never blocked admission
+                for req, fin_round, row in decode_jobs:
+                    with TraceAnnotation("router.fetch", rid=req.rid):
+                        req.image = np.asarray(jax.device_get(row))[0]
+                    req.finished_s = time.perf_counter() - t0
+                    req.finish_round = fin_round
+                    completed += 1
+                    yield {"event": "finished", "rid": req.rid,
+                           "replica": req.replica, "round": fin_round,
+                           "tier": req.tier, "image": req.image,
+                           "t_s": req.finished_s}
+                decode_jobs = []
+                for req, at_step, row in preview_jobs:
+                    with TraceAnnotation("router.fetch", rid=req.rid):
+                        img = np.asarray(jax.device_get(row))[0]
+                    req.previews += 1
+                    pv_t = time.perf_counter() - t0
+                    if req.first_preview_s is None:
+                        req.first_preview_s = pv_t
+                    yield {"event": "preview", "rid": req.rid,
+                           "replica": req.replica, "round": round_idx,
+                           "step": at_step, "image": img, "t_s": pv_t}
+                preview_jobs = []
+                if not stepping:
+                    if completed < len(requests) and pending:
+                        with TraceAnnotation("router.wait_arrival"):
+                            time.sleep(max(pending[0].arrival_s
+                                           - (time.perf_counter() - t0),
+                                           0.0))
                     continue
-                states[ri] = self.engines[ri].slot_step(states[ri])
-                step_calls += 1
-                step_wall += self.engines[ri].last_wall_s
-                stepped_rows += len(owners[ri])
-            round_idx += 1
-            # dispatch retirement decodes (async) and free the slots NOW
-            # — the freed rows are admissible next pass, the pixels are
-            # fetched after it
-            for ri in range(self.replicas):
-                if not owners[ri]:
-                    continue
-                eng = self.engines[ri]
-                done = [s for s in eng.finished_slots(states[ri])
-                        if s in owners[ri]]
-                if done:
-                    imgs = eng.decode_slots(states[ri], done)
-                    for j, slot in enumerate(done):
-                        decode_jobs.append((owners[ri].pop(slot),
-                                            round_idx, imgs[j:j + 1]))
-                    states[ri] = eng.retire(states[ri], done)
-            # progressive previews of rows still in flight
-            if self.preview_every and round_idx % self.preview_every == 0:
+                # one router round: step every occupied replica
                 for ri in range(self.replicas):
-                    slots = sorted(owners[ri])
-                    if not slots:
+                    if not owners[ri]:
+                        continue
+                    states[ri] = self.engines[ri].slot_step(states[ri])
+                    step_calls += 1
+                    stepped_rows += len(owners[ri])
+                round_idx += 1
+                # dispatch retirement decodes (async) and free the slots
+                # NOW — the freed rows are admissible next pass, the pixels
+                # are fetched after it
+                for ri in range(self.replicas):
+                    if not owners[ri]:
                         continue
                     eng = self.engines[ri]
-                    pv = eng.decode_preview(states[ri], slots)
-                    step_of = jax.device_get(states[ri].step_idx)
-                    for j, slot in enumerate(slots):
-                        preview_jobs.append((owners[ri][slot],
-                                             int(step_of[slot]),
-                                             pv[j:j + 1]))
+                    done = [s for s in eng.finished_slots(states[ri])
+                            if s in owners[ri]]
+                    if not done:
+                        continue
+                    with TraceAnnotation("router.retire"):
+                        imgs = eng.decode_slots(states[ri], done)
+                        for j, slot in enumerate(done):
+                            decode_jobs.append((owners[ri].pop(slot),
+                                                round_idx, imgs[j:j + 1]))
+                        states[ri] = eng.retire(states[ri], done)
+                # progressive previews of rows still in flight
+                if self.preview_every and round_idx % self.preview_every == 0:
+                    for ri in range(self.replicas):
+                        slots = sorted(owners[ri])
+                        if not slots:
+                            continue
+                        eng = self.engines[ri]
+                        with TraceAnnotation("router.preview"):
+                            pv = eng.decode_preview(states[ri], slots)
+                            step_of = jax.device_get(states[ri].step_idx)
+                            for j, slot in enumerate(slots):
+                                preview_jobs.append((owners[ri][slot],
+                                                     int(step_of[slot]),
+                                                     pv[j:j + 1]))
         self._states = states
         self._rounds = round_idx
         self._step_calls = step_calls
-        self._step_wall = step_wall
         self._stepped_rows = stepped_rows
 
     def run(self, requests: list, ledger: bool = False) -> dict:
@@ -334,7 +352,6 @@ class ClusterRouter:
             "slots_per_replica": self.slots_per_replica,
             "rounds": self._rounds,
             "engine_steps": self._step_calls,
-            "step_wall_s": self._step_wall,
             "mean_occupancy": self._stepped_rows / max(
                 self._step_calls * self.slots_per_replica, 1),
             "events": events,

@@ -14,6 +14,7 @@ must collect the same tests and only the worker running this file loads it.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,11 +47,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernel=None):
+    """Compile for the described chip; with ``kernel``, also assert the
+    kernel's custom call carries that HLO name, which the benchmark's
+    per-kernel roofline readers key on."""
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    if kernel is not None:
+        calls = re.findall(r"%(\w+?)(?:\.\d+)? = .*custom-call\(", text)
+        assert kernel in calls, calls
 
 
 F32 = jnp.float32
@@ -61,20 +68,22 @@ UNET_QKV = ((2, 8, 4096, 40), F32)
 def test_pssa_attention_compiles_unet_64x64(one_chip, patch):
     fn = functools.partial(pssa_attention, threshold=THRESHOLD, patch=patch,
                            interpret=False)
-    _compile(fn, one_chip, UNET_QKV, UNET_QKV, UNET_QKV)
+    _compile(fn, one_chip, UNET_QKV, UNET_QKV, UNET_QKV,
+             kernel="pssa_attention_kernel")
 
 
 def test_pssa_attention_compiles_dit_s2(one_chip):
     qkv = ((2, 6, 256, 64), F32)
     fn = functools.partial(pssa_attention, threshold=THRESHOLD, patch=16,
                            interpret=False)
-    _compile(fn, one_chip, qkv, qkv, qkv)
+    _compile(fn, one_chip, qkv, qkv, qkv, kernel="pssa_attention_kernel")
 
 
 def test_tips_cross_attention_compiles_clip_text(one_chip):
     text = ((2, 8, 77, 40), F32)
     fn = functools.partial(cross_attention_cas, interpret=False)
-    _compile(fn, one_chip, UNET_QKV, text, text)
+    _compile(fn, one_chip, UNET_QKV, text, text,
+             kernel="cross_attention_tips_kernel")
 
 
 def test_dbsc_bitslice_matmul_compiles_geglu(one_chip):
