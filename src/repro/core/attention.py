@@ -93,7 +93,7 @@ def self_attention_pssa_fused(q: jax.Array, k: jax.Array, v: jax.Array,
                               threshold: float = pssa.DEFAULT_THRESHOLD,
                               stats_rows: int | None = None,
                               interpret: bool | None = None,
-                              bq: int = 128, bk: int = 128,
+                              bq: int | None = None, bk: int | None = None,
                               row_stats: bool = False) -> SelfAttnOut:
     """``self_attention_pssa`` through the blocked Pallas kernel.
 

@@ -2,7 +2,8 @@
 
 The five ``KernelPolicy`` block knobs (``attn_block_q``/``attn_block_k``,
 ``cross_block_q``, ``bitmap_block_rows``, ``reuse_block_patches``) default
-to safe-everywhere values; the right blocks depend on the backend and the
+to safe-everywhere values (the attention pair to the PSSA op's tiling by
+its operand shape); the right blocks depend on the backend and the
 operand geometry.  This module sweeps each kernel family's candidates
 with the same min-of-k block-until-ready timing every bench uses
 (``runtime.min_wall_s``) and persists the winners to a committed JSON

@@ -65,7 +65,8 @@ class KernelPolicy:
     Frozen + hashable so it can live inside ``UNetConfig`` and flow through
     jit closures.  ``interpret=None`` auto-selects per backend; block sizes
     are forwarded to the Pallas wrappers (which pad-and-slice, so any
-    geometry is legal).
+    geometry is legal).  The attention blocks default to None: the PSSA
+    op then tiles by its operand shape (``pssa_attention.ops.default_blocks``).
     """
     self_attention: str = "reference"
     cross_attention: str = "reference"
@@ -73,8 +74,8 @@ class KernelPolicy:
     bitmap: str = "reference"
     reuse: str = "reference"
     interpret: bool | None = None
-    attn_block_q: int = 128
-    attn_block_k: int = 128
+    attn_block_q: int | None = None
+    attn_block_k: int | None = None
     cross_block_q: int = 128
     bitmap_block_rows: int = 64
     reuse_block_patches: int = 8

@@ -22,14 +22,18 @@ max/sum, so the kernel is two-pass (FlashAttention-2 style):
           XORs against zeros, i.e. is counted verbatim, matching
           ``core.pssa.patch_xor``.  Rotation keeps every array at the
           block's (bq, bk) shape, so no unaligned lane slice is needed.
+          Both counters are carried as (bq, 128) lane-wise partials and
+          reduced across lanes once per query block, not once per key
+          block.
 
 ``kv_len`` supports block-padded operands: key columns >= kv_len are masked
 to -inf before the softmax statistics and excluded from every counter, so
 padding to the block multiple (see ops.py) is exact.
 
 Grid: (batch*heads, Tq/bq); the full K/V stripe of one (batch, head) lives
-in VMEM (T x d x 2 operands — <= 4 MB for T=4096, d=64, fp32; half that in
-bf16 on silicon).  The per-query counters leave the kernel as lane-dense
+in VMEM: K and V, each (T, d) padded to 128 lanes and double-buffered, take
+8 MiB at T=4096 in float32, and the (bq, bk) temporaries of a block come on
+top.  The per-query counters leave the kernel as lane-dense
 (BH, 1, Tq) arrays in (1, 1, bq) blocks, the layout the TPU's (8, 128)
 tiling accepts; the wrapper reshapes them to (BH, Tq).
 """
@@ -45,6 +49,15 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.runtime import resolve_interpret
 
 NEG_INF = -1e30
+LANES = 128
+
+
+def _lane_partial(x: jax.Array, w: int) -> jax.Array:
+    """(bq, bk) int32 -> (bq, w): the sum of x's w-lane slices."""
+    out = x[:, :w]
+    for j in range(1, x.shape[1] // w):
+        out = out + x[:, j * w:(j + 1) * w]
+    return out
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, nnz_ref, *rest, bk: int,
@@ -56,6 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, nnz_ref, *rest, bk: int,
     nk = kdim // bk
     bq = q.shape[0]
     padded = kv_len < kdim                        # static: mask the tail
+    w = LANES if bk % LANES == 0 else bk          # counter partials' width
 
     def kv_valid(s):                              # (1, bk) bool, col < kv_len
         col = s * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
@@ -93,7 +107,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, nnz_ref, *rest, bk: int,
             keep = jnp.logical_and(keep, kv_valid(s))
         p = jnp.where(keep, p, 0.0)                # PSSA step 1: prune
         acc = acc + jnp.dot(p, vblk, preferred_element_type=jnp.float32)
-        nnz = nnz + jnp.sum(keep.astype(jnp.int32), axis=-1)
+        nnz = nnz + _lane_partial(keep.astype(jnp.int32), w)
         if patch is None:
             return acc, nnz
         # PSXU accounting: XOR each bitmap patch against its left neighbour.
@@ -105,27 +119,26 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, nnz_ref, *rest, bk: int,
         delta = bits != jnp.where(lane < patch, prev, rolled)
         if padded:                     # patches past kv_len are padding
             delta = jnp.logical_and(delta, kv_valid(s))
-        xor_cnt = xor_cnt + jnp.sum(delta.astype(jnp.int32), axis=-1)
+        xor_cnt = xor_cnt + _lane_partial(delta.astype(jnp.int32), w)
         return acc, nnz, xor_cnt, rolled
 
     acc0 = jnp.zeros_like(o_ref[0])
-    nnz0 = jnp.zeros((bq,), jnp.int32)
+    part0 = jnp.zeros((bq, w), jnp.int32)
     if patch is None:
-        acc, nnz = jax.lax.fori_loop(0, nk, pass2, (acc0, nnz0))
+        acc, nnz = jax.lax.fori_loop(0, nk, pass2, (acc0, part0))
     else:
         prev0 = jnp.zeros((bq, bk), jnp.int32)
         acc, nnz, xor_cnt, _ = jax.lax.fori_loop(
-            0, nk, pass2, (acc0, nnz0, jnp.zeros((bq,), jnp.int32), prev0))
-        xor_ref[0] = xor_cnt.reshape(1, bq)
+            0, nk, pass2, (acc0, part0, part0, prev0))
+        xor_ref[0] = jnp.sum(xor_cnt, axis=-1).reshape(1, bq)
     o_ref[0] = acc
-    nnz_ref[0] = nnz.reshape(1, bq)
+    nnz_ref[0] = jnp.sum(nnz, axis=-1).reshape(1, bq)
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bk", "threshold",
                                              "interpret", "kv_len", "patch"))
 def pssa_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
-                          threshold: float,
-                          bq: int = 128, bk: int = 128,
+                          threshold: float, *, bq: int, bk: int,
                           interpret: bool | None = None,
                           kv_len: int | None = None,
                           patch: int | None = None):

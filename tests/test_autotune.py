@@ -33,6 +33,7 @@ from repro.kernels.bitslice_matmul.ops import bitslice_matmul
 from repro.kernels.bitslice_matmul.ref import (bitslice_matmul_int8,
                                                bitslice_matmul_ref)
 from repro.kernels.dispatch import KernelPolicy
+from repro.kernels.pssa_attention import ops as pssa_ops
 from repro.kernels.pssa_attention.ops import pssa_attention
 from repro.kernels.patch_bitmap.ops import patch_bitmap
 from repro.kernels.patch_reuse.ops import patch_delta
@@ -127,6 +128,24 @@ def test_bad_entries_rejected_loudly(tmp_path, entries, match):
         autotune.load_table(path)
 
 
+def _kernel_blocks(monkeypatch, policy, geom):
+    """(bq, bk) the PSSA kernel runs with when dispatched under ``policy``."""
+    seen = []
+    real = pssa_ops.pssa_attention_kernel
+
+    def spy(*args, **kw):
+        seen.append((kw["bq"], kw["bk"]))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(pssa_ops, "pssa_attention_kernel", spy)
+    jax.clear_caches()                 # a cached trace would skip the spy
+    b, h, t, d, patch = geom
+    q = jnp.ones((b, h, t, d))
+    dispatch.self_attention(policy, q, q, q, patch=patch,
+                            threshold=1.0 / 1024.0)
+    return seen[-1]
+
+
 def test_lookup_hits_and_dispatch_blocks(tmp_path, monkeypatch):
     geom = (1, 2, 64, 8, 16)
     key = autotune.make_key(jax.default_backend(), "self_attention", geom)
@@ -137,16 +156,18 @@ def test_lookup_hits_and_dispatch_blocks(tmp_path, monkeypatch):
 
     assert autotune.lookup("self_attention", geom) == {
         "attn_block_q": 64, "attn_block_k": 32}
-    # dispatch resolution: tuned policy takes the table's winner, the
-    # untuned policy (and unknown geometries) keep the field defaults
+    # dispatch resolution: tuned policy takes the table's winner; the
+    # untuned policy (and unknown geometries) leave the blocks to the
+    # op's geometry rule, which is what reaches the kernel
     tuned = KernelPolicy.autotuned()
     assert dispatch._blocks(tuned, "self_attention", geom) == {
         "attn_block_q": 64, "attn_block_k": 32}
-    assert dispatch._blocks(KernelPolicy.fused(), "self_attention",
-                            geom) == {"attn_block_q": 128,
-                                      "attn_block_k": 128}
-    assert dispatch._blocks(tuned, "self_attention", (1, 2, 128, 8, 16)) \
-        == {"attn_block_q": 128, "attn_block_k": 128}
+    assert _kernel_blocks(monkeypatch, tuned, geom) == (64, 32)
+    assert _kernel_blocks(monkeypatch, KernelPolicy.fused(), geom) \
+        == pssa_ops.default_blocks(64)
+    unknown = (1, 2, 128, 8, 16)
+    assert _kernel_blocks(monkeypatch, tuned, unknown) \
+        == pssa_ops.default_blocks(128)
 
 
 def test_committed_table_is_valid():

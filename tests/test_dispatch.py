@@ -361,7 +361,11 @@ def test_no_sas_materialized_on_fused_path():
     # ffn_mult=2 de-aliases the GEGLU hidden width from T (at smoke
     # defaults 2*4*32 == 256 == T, so a benign FFN activation would trip
     # the (T, T) probe); with it, only a score matrix can end in (T, T).
-    ucfg = dataclasses.replace(PipelineConfig.smoke().unet, ffn_mult=2)
+    # 32x32 latents put T = 1024 above the kernel's largest tile
+    # (``default_blocks``): at T <= 512 one on-chip (bq, bk) tile of the
+    # kernel body is itself (T, T).
+    ucfg = dataclasses.replace(PipelineConfig.smoke().unet, ffn_mult=2,
+                               latent_size=32)
     params = init_unet_params(jax.random.PRNGKey(42), ucfg)
     t_big = ucfg.latent_size ** 2          # largest self-attention T
     # positive control: the reference path DOES materialize the (.., T, T)

@@ -15,7 +15,9 @@ from repro.kernels.bitslice_matmul.ref import bitslice_matmul_ref
 from repro.kernels.patch_bitmap.kernel import patch_bitmap_kernel
 from repro.kernels.patch_bitmap.ref import patch_bitmap_ref
 from repro.kernels.pssa_attention.kernel import pssa_attention_kernel
-from repro.kernels.pssa_attention.ref import pssa_attention_ref
+from repro.kernels.pssa_attention.ops import default_blocks, pssa_attention
+from repro.kernels.pssa_attention.ref import (pssa_attention_ref,
+                                              pssa_attention_stats_ref)
 
 
 # ----------------------------------------------------------------------------
@@ -93,7 +95,9 @@ def test_pssa_attention_matches_ref(bh, t, d):
     k = jax.random.PRNGKey(0)
     q, kk, v = (jax.random.normal(jax.random.PRNGKey(i), (bh, t, d))
                 for i in range(3))
-    out, nnz = pssa_attention_kernel(q, kk, v, threshold=1.0 / 1024.0)
+    bq, bk = default_blocks(t)
+    out, nnz = pssa_attention_kernel(q, kk, v, threshold=1.0 / 1024.0,
+                                     bq=bq, bk=bk)
     oref, nref = pssa_attention_ref(q, kk, v, threshold=1.0 / 1024.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oref),
                                rtol=2e-5, atol=2e-5)
@@ -113,13 +117,74 @@ def test_pssa_attention_block_sweep(bq, bk):
 
 def test_pssa_attention_zero_threshold_is_exact_softmax():
     q = jax.random.normal(jax.random.PRNGKey(2), (2, 256, 64))
-    out, nnz = pssa_attention_kernel(q, q, q, threshold=0.0)
+    out, nnz = pssa_attention_kernel(q, q, q, threshold=0.0, bq=128, bk=128)
     probs = jax.nn.softmax(
         jnp.einsum("bqd,bkd->bqk", q, q) / jnp.sqrt(64.0), -1)
     oref = jnp.einsum("bqk,bkd->bqd", probs, q)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oref),
                                rtol=2e-5, atol=2e-5)
     assert (np.asarray(nnz) == 256).all()
+
+
+@pytest.mark.parametrize("bh,t,d,patch", [(2, 2048, 40, 64),
+                                          (1, 2112, 40, 64)],
+                         ids=["t2048_d40", "t2112_padded"])
+def test_pssa_attention_default_tiling_matches_stats_ref(bh, t, d, patch):
+    """At the op's own tiling, with at least two query and two key blocks
+    so the patch-XOR carry crosses key blocks: out within 2e-5, both
+    counters exact."""
+    bq, bk = default_blocks(t)
+    assert t > bq and t > bk
+    q, kk, v = (jax.random.normal(jax.random.PRNGKey(10 + i), (bh, t, d))
+                for i in range(3))
+    thr = 1.0 / 1024.0
+    out, nnz, xor = pssa_attention(q[None], kk[None], v[None], thr,
+                                   patch=patch, interpret=True)
+    oref, nref, xref = pssa_attention_stats_ref(q, kk, v, thr, patch)
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(oref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(nnz[0]), np.asarray(nref))
+    np.testing.assert_array_equal(np.asarray(xor[0]), np.asarray(xref))
+
+
+@pytest.mark.parametrize("bh,t,d,patch", [(2, 1024, 80, 32),
+                                          (1, 2048, 40, 64)],
+                         ids=["t1024_d80", "t2048_d40"])
+def test_pssa_attention_default_tiling_matches_128_tiling(bh, t, d, patch):
+    """The op's own tiling against the fixed 128 x 128 it replaced: both
+    counters exact and out within 2e-5.  (Here, on t1024_d80, one query
+    row's keep decision differs from the materialising reference at every
+    tiling alike: a score one ulp either side of the threshold, as the
+    kernel scales q before the QK matmul and the reference after it.)"""
+    q, kk, v = (jax.random.normal(jax.random.PRNGKey(10 + i), (1, bh, t, d))
+                for i in range(3))
+    thr = 1.0 / 1024.0
+    got = pssa_attention(q, kk, v, thr, patch=patch, interpret=True)
+    old = pssa_attention(q, kk, v, thr, patch=patch, interpret=True,
+                         bq=128, bk=128)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(old[0]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(old[1]))
+    np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(old[2]))
+
+
+# the served geometries (B, H, T, d) with their PSXU patch
+SERVED_PSSA = {
+    "unet_64x64": ((8, 8, 4096, 40), 64),
+    "unet_32x32": ((8, 8, 1024, 80), 32),
+    "unet_16x16": ((8, 8, 256, 160), 16),
+    "dit_s2": ((16, 6, 256, 64), 16),
+}
+
+
+@pytest.mark.parametrize("name,blocks", [
+    ("unet_64x64", (512, 1024)), ("unet_32x32", (512, 1024)),
+    ("unet_16x16", (256, 256)), ("dit_s2", (256, 256))])
+def test_pssa_default_blocks_at_served_geometries(name, blocks):
+    (_, _, t, _), patch = SERVED_PSSA[name]
+    assert default_blocks(t) == blocks
+    bq, bk = blocks
+    assert bq <= t and bk <= t and bk % patch == 0 and t % bq == 0
 
 
 # ----------------------------------------------------------------------------

@@ -64,12 +64,16 @@ F32 = jnp.float32
 UNET_QKV = ((2, 8, 4096, 40), F32)
 
 
-@pytest.mark.parametrize("patch", [None, 64], ids=["no_patch", "patch64"])
-def test_pssa_attention_compiles_unet_64x64(one_chip, patch):
+@pytest.mark.parametrize("qkv,patch", [
+    (UNET_QKV, None), (UNET_QKV, 64),
+    (((2, 8, 1024, 80), F32), 32), (((2, 8, 256, 160), F32), 16)],
+    ids=["no_patch", "patch64", "32x32_patch32", "16x16_patch16"])
+def test_pssa_attention_compiles_unet_64x64(one_chip, qkv, patch):
+    """Every UNet level (64x64, 32x32, 16x16 latents) at the op's own
+    tiling: a tile the chip's VMEM cannot hold is refused here."""
     fn = functools.partial(pssa_attention, threshold=THRESHOLD, patch=patch,
                            interpret=False)
-    _compile(fn, one_chip, UNET_QKV, UNET_QKV, UNET_QKV,
-             kernel="pssa_attention_kernel")
+    _compile(fn, one_chip, qkv, qkv, qkv, kernel="pssa_attention_kernel")
 
 
 def test_pssa_attention_compiles_dit_s2(one_chip):
