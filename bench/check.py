@@ -4,8 +4,9 @@ A run records, for a seed-drawn sample of its requests (one per slot), the
 latents the served slot step produced after each of the request's denoising
 steps, and the image the served decode produced (``System.serve``).  Once
 the window has closed and the served program is freed, the float32
-reference (``reference.py``, matmuls at ``highest``) is teacher-forced
-along the served trajectory, one request at a time.  The numbers compared
+reference (the configuration's reference module, ``reference.py`` unless
+it names another; matmuls at ``highest``) is teacher-forced along the
+served trajectory, one request at a time.  The numbers compared
 are named in the configuration file (``check``: name -> limit):
 
 * ``step_upd_rel_err_max``: for every step i, the reference's step from
@@ -49,9 +50,9 @@ def readings(cfg: dict, weights, sample: list) -> dict:
     import jax
     import jax.numpy as jnp
 
-    import reference
+    import config_modules
 
-    pipe = reference.Pipeline(cfg)
+    pipe = config_modules.reference(cfg).Pipeline(cfg)
     n = cfg["sampler"]["num_inference_steps"]
     w = weights
     out = {"step_upd": [], "decode": []}

@@ -5,7 +5,8 @@
       [--modes bf16 int8]
 
 For each seed: the cell's weights and the first ``--requests`` requests of
-its traffic content, run by the reference in the served program's place in
+its traffic content, run by the configuration's reference module
+(``config_modules.py``) in the served program's place in
 the precision next below each of the two the configuration states (its
 ``precision``), and judged by the run's own comparison (``check.py``)
 against the float32 reference:
@@ -49,8 +50,9 @@ def trajectories(cfg: dict, w, toks, uncond, lat, mode: str) -> list:
     import jax.numpy as jnp
     import numpy as np
 
-    import reference
+    import config_modules
 
+    reference = config_modules.reference(cfg)
     dtype = jnp.bfloat16 if mode == "bf16" else jnp.float32
     w = jax.tree.map(lambda x: x.astype(dtype), w)
     ctl = reference.Pipeline(cfg)
@@ -75,12 +77,16 @@ def trajectories(cfg: dict, w, toks, uncond, lat, mode: str) -> list:
 
 def readings(cfg: dict, seeds, n_requests: int, mode: str) -> list:
     """Per seed, the check's readings with the control in the program's
-    place: its own trajectory and image, judged by ``check.readings``."""
+    place: its own trajectory and image, judged by ``check.readings``.
+    Raises ``config_modules.Unimplemented`` before any weights are made
+    where the configuration's modules do not implement it."""
     import check
+    import config_modules
     import traffic
     import weights
     from system import abstract_weights
 
+    config_modules.refuse_unimplemented(cfg)
     abstract = abstract_weights(cfg)
     out = []
     for seed in seeds:
@@ -103,10 +109,16 @@ def main(argv=None) -> int:
     ap.add_argument("--modes", choices=sorted(MODES), nargs="+",
                     default=sorted(MODES))
     args = ap.parse_args(argv)
+    import config_modules
     import harness
-    harness.use_compile_cache()
     with open(os.path.join(HERE, "configs", f"{args.config}.json")) as f:
         cfg = json.load(f)
+    try:
+        config_modules.refuse_unimplemented(cfg)
+    except config_modules.Unimplemented as e:
+        print(f"control: {e}", file=sys.stderr)
+        return 1
+    harness.use_compile_cache()
     for mode in args.modes:
         for row in readings(cfg, args.seeds, args.requests, mode):
             print(json.dumps({"config": args.config, **row}), flush=True)

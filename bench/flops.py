@@ -17,10 +17,46 @@ requires: ``4 B H Tq Tk d`` FLOPs (QK and PV) and the float32 Q, K, V and O
 operands plus the per-query side outputs (PSSA's two counters, TIPS's CAS).
 The PSSA kernel's second QK pass and its counter work are not required
 work, so they show as the distance from the roofline.
+
+This is the default counts module of a configuration (``config_modules.py``
+states the interface): ``image_flops(cfg)`` and ``attention_calls(cfg,
+slots)``.  ``IMPLEMENTS`` declares the configuration keys it counts: a UNet
+with one transformer block after each resnet, no mid block and one head
+count, under classifier-free guidance.
 """
 from __future__ import annotations
 
 F32 = 4
+
+
+def _guided(scale) -> bool:
+    """a number other than 1: the counts hold classifier-free guidance's
+    uncond rows"""
+    return isinstance(scale, (int, float)) and scale != 1
+
+
+_NUMBER = (int, float)
+_SHARED = {"in_channels": int, "out_channels": int, "num_heads": int,
+           "context_dim": int, "text_len": int, "time_dim": int,
+           "latent_size": int, "groups": int, "ffn_mult": int,
+           "pssa_threshold": _NUMBER, "tips_threshold": _NUMBER}
+IMPLEMENTS = {
+    "denoiser": {
+        "unet": {**_SHARED, "family": ["unet"], "block_channels": list,
+                 "down_attn": list, "resnets_per_down": int,
+                 "resnets_per_up": int, "has_mid_block": [False],
+                 "transformer_depth": [1]},
+        "dit": {**_SHARED, "family": ["dit"], "patch": int,
+                "hidden_size": int, "depth": int},
+    },
+    "text": {"vocab_size": int, "max_len": int, "d_model": int,
+             "num_layers": int, "num_heads": int, "d_ff": int},
+    "vae": {"latent_channels": int, "out_channels": int, "channels": list,
+            "resnets_per_stage": int, "groups": int, "scale_factor": _NUMBER},
+    "sampler": {"num_train_steps": int, "num_inference_steps": int,
+                "beta_start": _NUMBER, "beta_end": _NUMBER,
+                "guidance_scale": _guided, "tips_active_iters": int},
+}
 
 
 def matmul(m: int, k: int, n: int) -> int:

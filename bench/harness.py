@@ -1,10 +1,11 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Everything a cell needs is found by name from data: the cell in
-``BENCHMARK.json``, its configuration file, its traffic mix
+``BENCHMARK.json``, its configuration file with the reference and counts
+modules it names (``config_modules.py``), its traffic mix
 (``traffic/<name>.json``) and one reader per per-layer metric
-(``metrics/<name>.py``).  Adding a cell, a mix or a metric adds files and
-entries; no code here names one.
+(``metrics/<name>.py``).  Adding a cell, a mix, a configuration or a
+metric adds files and entries; no code here names one.
 
 The window is ``--seconds`` long on the serving clock and starts once the
 traffic's ramp (one generation) has passed; set-up (``setup_s``) is
@@ -211,6 +212,9 @@ def run_cell(spec: dict, cell: dict, *, seed: int, seconds: float,
     """Run one cell; returns the result object (the last stdout line).
 
     ``root`` holds ``BENCHMARK.json`` and the data files under ``bench/``.
+    A configuration whose reference or counts module does not implement
+    one of its keys is refused (``config_modules.Unimplemented``) before
+    anything is set up.
     The tests run a cell on the CPU through three hooks: no check that
     the kernels are the compiled route, ``peaks`` given for a device the
     table does not have, and ``patch(system)``, which may replace parts of
@@ -219,14 +223,16 @@ def run_cell(spec: dict, cell: dict, *, seed: int, seconds: float,
     import jax
 
     import check
-    import flops
+    import config_modules
     import traffic
     import weights
     from system import StreamDeadline, System
 
+    cfg = load_config(spec, cell, root)
+    config_modules.refuse_unimplemented(cfg)
+    counts = config_modules.counts(cfg)
     counter = CompileCounter()
     say(f"compile cache {use_compile_cache(os.path.join(root, '.jax_cache'))}")
-    cfg = load_config(spec, cell, root)
     mix = traffic.load(cell["traffic"], os.path.join(root, "bench"))
     dev = device_info()
     system = System(cfg, lambda abstract: weights.make(abstract, seed),
@@ -246,7 +252,7 @@ def run_cell(spec: dict, cell: dict, *, seed: int, seconds: float,
     step_s = system.step_seconds()
     n_steps = cfg["sampler"]["num_inference_steps"]
     ramp_s = mix.get("ramp_generations", 1.0) * n_steps * step_s
-    image_flops = flops.image_flops(cfg)
+    image_flops = counts.image_flops(cfg)
     peaks = peaks or load_peaks(dev["kind"])
     rate_bound = peaks["bf16_flops_per_s"] / image_flops
     due = traffic.arrivals(mix, seed=seed, window_s=seconds, ramp_s=ramp_s,
@@ -399,7 +405,7 @@ def run_cell(spec: dict, cell: dict, *, seed: int, seconds: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx["trace"] = summary
         ctx["traced_window"] = (traced_from, traced_to)
-        ctx["attention_calls"] = flops.attention_calls(cfg, system.slots)
+        ctx["attention_calls"] = counts.attention_calls(cfg, system.slots)
         metrics = {}
         for m in layer:
             v = reader(m["name"])(ctx)

@@ -26,6 +26,14 @@ straightforward ``jax.numpy``:
 ``jax.default_matmul_precision("highest")``), bfloat16 for the control.
 Every function takes plain nested dicts and lists of arrays and a dict of
 sizes read from the configuration file.
+
+This is the default reference module of a configuration
+(``config_modules.py`` states the interface): ``Pipeline(cfg)`` with
+``enc(text_weights, tokens)``, ``step(weights, x, ctx, unctx, i)`` and
+``dec(vae_weights, x)``, and ``operands(fn)``.  ``IMPLEMENTS`` declares the
+configuration keys it implements: a UNet with one transformer block after
+each resnet (``transformer_depth`` 1), no mid block, and one head count for
+every stage; one CLIP text tower.
 """
 from __future__ import annotations
 
@@ -36,6 +44,29 @@ import jax
 import jax.numpy as jnp
 
 _round = None       # rounding of both operands of every product (control)
+
+_NUMBER = (int, float)
+_SHARED = {"in_channels": int, "out_channels": int, "num_heads": int,
+           "context_dim": int, "text_len": int, "time_dim": int,
+           "latent_size": int, "groups": int, "ffn_mult": int,
+           "pssa_threshold": _NUMBER, "tips_threshold": _NUMBER}
+IMPLEMENTS = {
+    "denoiser": {
+        "unet": {**_SHARED, "family": ["unet"], "block_channels": list,
+                 "down_attn": list, "resnets_per_down": int,
+                 "resnets_per_up": int, "has_mid_block": [False],
+                 "transformer_depth": [1]},
+        "dit": {**_SHARED, "family": ["dit"], "patch": int,
+                "hidden_size": int, "depth": int},
+    },
+    "text": {"vocab_size": int, "max_len": int, "d_model": int,
+             "num_layers": int, "num_heads": int, "d_ff": int},
+    "vae": {"latent_channels": int, "out_channels": int, "channels": list,
+            "resnets_per_stage": int, "groups": int, "scale_factor": _NUMBER},
+    "sampler": {"num_train_steps": int, "num_inference_steps": int,
+                "beta_start": _NUMBER, "beta_end": _NUMBER,
+                "guidance_scale": _NUMBER, "tips_active_iters": int},
+}
 
 
 @contextlib.contextmanager

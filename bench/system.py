@@ -23,42 +23,80 @@ ENGINE_CALLS = ("admit", "slot_step", "finished_slots", "decode_slots",
                 "retire")
 
 
+# configuration groups named apart from the ``PipelineConfig`` field they
+# fill; every other top-level group named as a field fills that field
+FIELD_OF_GROUP = {"denoiser": "unet", "sampler": "ddim"}
+
+
 def pipeline_config(cfg: dict):
-    """The program's ``PipelineConfig`` for a configuration file."""
+    """The program's ``PipelineConfig`` for a configuration file.
+
+    The ``denoiser`` group is built into the config class of the family it
+    names, as the program registers it (``repro.diffusion.denoiser``); any
+    other top-level group named as a ``PipelineConfig`` field (``text``,
+    ``vae``, ``sampler`` for ``ddim``) into the class that field holds by
+    default.  Inside a group, lists become tuples and a nested object
+    becomes the dataclass its field holds by default, recursively.
+    """
     from repro.core.policies import ServePolicies
+    from repro.diffusion import denoiser
     from repro.diffusion.pipeline import PipelineConfig
-    from repro.diffusion.sampler import DDIMConfig
-    from repro.diffusion.text_encoder import TextEncoderConfig
-    from repro.diffusion.vae import VAEConfig
 
     den = dict(cfg["denoiser"])
     family = den.pop("family")
     tips_threshold = den.pop("tips_threshold")
-    if family == "unet":
-        from repro.diffusion.unet import UNetConfig as cls
-    elif family == "dit":
-        from repro.diffusion.dit import DiTConfig as cls
-    else:
-        raise ValueError(f"denoiser family {family!r} is not unet or dit")
-
-    def build(klass, group: dict):
-        names = {f.name for f in dataclasses.fields(klass)}
-        unknown = set(group) - names
-        if unknown:
-            raise ValueError(f"{klass.__name__} has no field {sorted(unknown)}")
-        return klass(**{k: tuple(v) if isinstance(v, list) else v
-                        for k, v in group.items()})
-
-    pipe = PipelineConfig(unet=build(cls, den),
-                          text=build(TextEncoderConfig, cfg["text"]),
-                          vae=build(VAEConfig, cfg["vae"]),
-                          ddim=build(DDIMConfig, cfg["sampler"]))
+    denoiser._ensure_registered()
+    if family not in denoiser._REGISTRY:
+        raise ValueError(f"denoiser family {family!r} is not registered; "
+                         f"the program registers "
+                         f"{sorted(denoiser._REGISTRY)}")
+    fields = {f.name: f for f in dataclasses.fields(PipelineConfig)}
+    built = {}
+    for group, values in cfg.items():
+        name = FIELD_OF_GROUP.get(group, group)
+        if name not in fields:
+            continue
+        if name in built:
+            raise ValueError(f"two configuration groups fill "
+                             f"PipelineConfig.{name}")
+        if group == "denoiser":
+            built[name] = _build(denoiser._REGISTRY[family].config_cls, den)
+        else:
+            built[name] = _build(type(_default(fields[name])), values)
     serve = cfg["serve"]
     policies = ServePolicies.parse(
         kernels=serve["kernels"],
         tips=f"{serve['tips']},threshold={tips_threshold}",
         reuse=serve["reuse"])
-    return policies.apply(pipe)
+    return policies.apply(PipelineConfig(**built))
+
+
+def _default(field):
+    if field.default_factory is not dataclasses.MISSING:
+        return field.default_factory()
+    return field.default
+
+
+def _build(klass, group: dict):
+    """``klass`` from a configuration group (see ``pipeline_config``)."""
+    fields = {f.name: f for f in dataclasses.fields(klass)}
+    unknown = set(group) - set(fields)
+    if unknown:
+        raise ValueError(f"{klass.__name__} has no field {sorted(unknown)}")
+
+    def value(key, v):
+        if isinstance(v, dict):
+            default = _default(fields[key])
+            if not dataclasses.is_dataclass(default):
+                raise ValueError(f"{klass.__name__}.{key} holds no dataclass "
+                                 f"by default, so it takes no group")
+            return _build(type(default), v)
+        return _tuples(v)
+    return klass(**{k: value(k, v) for k, v in group.items()})
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
 
 
 def abstract_weights(cfg: dict) -> dict:

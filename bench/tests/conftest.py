@@ -25,9 +25,10 @@ SMOKE_DENOISER = {
 }
 
 
-def smoke_config(name: str, steps: int = 3) -> dict:
-    """A configuration file of the benchmark, cut to CPU-test size."""
-    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
+def smoke_config(name: str, steps: int = 3, folder: str = "configs") -> dict:
+    """A configuration file of the benchmark (``bench/<folder>/<name>.json``),
+    cut to CPU-test size."""
+    with open(os.path.join(BENCH, folder, f"{name}.json")) as f:
         cfg = json.load(f)
     cfg["denoiser"].update(SMOKE_DENOISER[cfg["denoiser"]["family"]])
     cfg["text"].update(SMOKE_TEXT)
