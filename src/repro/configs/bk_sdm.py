@@ -1,4 +1,8 @@
-"""The paper's own workload: BK-SDM-Tiny text-to-image pipeline.
+"""The paper's own workload: the BK-SDM text-to-image pipeline.
+
+The served geometry is BK-SDM-Small's (482.3M UNet parameters, the
+published 0.48B), though the program has long called it BK-SDM-Tiny:
+Tiny (0.32B) also drops the innermost 1280-channel stage and its up stage.
 
 Not one of the 10 assigned LM architectures — this is the diffusion config
 the processor was evaluated on (MS-COCO, 25 DDIM iterations).  Exposed here
@@ -16,7 +20,7 @@ from repro.diffusion.vae import VAEConfig
 from repro.kernels.dispatch import KernelPolicy
 
 CONFIG = PipelineConfig(
-    unet=UNetConfig(),            # BK-SDM-Tiny geometry (full)
+    unet=UNetConfig(),            # BK-SDM-Small geometry (full)
     text=TextEncoderConfig(),     # CLIP ViT-L/14 text tower geometry
     vae=VAEConfig(),
     ddim=DDIMConfig(num_inference_steps=25),

@@ -27,15 +27,21 @@ Contract (see DESIGN.md §11 for the full statement):
 
 ``apply(params, latents, timesteps, context, **kw)``
     Pure forward.  ``latents`` (B, S, S, C), ``timesteps`` (B,),
-    ``context`` (B or 2B, T_text, ctx_dim).  Keywords — all optional,
-    all with UNet-identical semantics:
+    ``context`` the encoded text as ``text_encoder.encode_text`` gives
+    it: a (B or 2B, T_text, ctx_dim) array, or a pytree of such leaves
+    where the family reads more than the text states (SDXL's "text_time"
+    UNet takes (states, pooled text)); the runtime fuses, slices and
+    stores it leaf by leaf.  Keywords — all optional, all with
+    UNet-identical semantics:
 
     - ``tips_active``: scalar or (B,) per-row TIPS activity;
     - ``stats_rows`` (static): restrict stats to the first N rows;
     - ``cfg_dup`` (static): shared-prefix CFG dedup — latents carry the
       cond half only, context carries [cond | uncond]; the hidden state
-      is tiled to 2B rows at the first cross-attention and ``eps`` comes
-      back with 2B rows (split by ``sampler.guided_eps``);
+      is tiled to 2B rows before the first operation that reads the
+      text (the first cross-attention, or the first resnet where the
+      time embedding holds pooled text) and ``eps`` comes back with 2B
+      rows (split by ``sampler.guided_eps``);
     - ``row_stats`` (static): per-row integer counters (``SlotStats``)
       instead of folded stats;
     - ``reuse_cache``: a ``core.reuse.ReuseCache`` with one

@@ -241,8 +241,8 @@ def dit_forward(params, latents, timesteps, context, cfg: DiTConfig,
         if needs_dup:
             temb = jnp.concatenate([temb, temb], axis=0)
             needs_dup = False
-        pssa_stats.append(sa)
-        tips_stats.append(ca)
+        pssa_stats.extend(sa)
+        tips_stats.extend(ca)
         if reuse_on:
             new_layer_caches.append(ru[0])
             reuse_stats.append(ru[1])

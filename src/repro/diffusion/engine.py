@@ -51,7 +51,8 @@ from repro.diffusion.denoiser import make_denoiser
 from repro.diffusion.sampler import (denoise_step, sample_scan,
                                      sample_scan_reuse)
 from repro.diffusion.stats import LedgerAccum, attn_layer_order
-from repro.diffusion.text_encoder import encode_text, init_text_encoder_params
+from repro.diffusion.text_encoder import (encode_text, encoded_zeros,
+                                          init_text_encoder_params)
 from repro.diffusion.vae import decode, init_vae_params
 from repro.launch.mesh import dp_axes_of, dp_size_of, mesh_signature
 
@@ -76,11 +77,14 @@ class SlotState:
     buckets each executed step scatters into.  ``uncond_context`` is
     ``None`` (static, via the treedef) when the engine's config disables
     CFG.  The whole state is donated to the jitted ``slot_step``
-    executable, so a serving loop updates it in place.
+    executable, so a serving loop updates it in place.  ``context`` and
+    ``uncond_context`` hold the encoded text as ``encode_text`` gives it:
+    a (S, Tk, d) array, or (states, pooled text) where the family reads
+    the pooled text too (SDXL).
     """
     latents: jax.Array                     # (S, s, s, C)
-    context: jax.Array                     # (S, Tk, d) encoded cond text
-    uncond_context: Optional[jax.Array]    # (S, Tk, d) or None
+    context: object                        # encoded cond text, S rows
+    uncond_context: Optional[object]       # the same for uncond, or None
     step_idx: jax.Array                    # (S,) int32
     active: jax.Array                      # (S,) bool
     accum: LedgerAccum
@@ -109,6 +113,16 @@ class SlotState:
     @property
     def num_slots(self) -> int:
         return int(self.step_idx.shape[0])
+
+
+def _row0(enc):
+    """Row 0 of every leaf of an encoded text (one slice per leaf)."""
+    return jax.tree.map(lambda x: x[0], enc)
+
+
+def _set_row(enc, slot, row):
+    """``enc`` with row ``slot`` of every leaf set from ``row`` (traced)."""
+    return jax.tree.map(lambda x, r: x.at[slot].set(r), enc, row)
 
 
 def _check_cfg_inputs(guidance_scale: float, uncond_tokens) -> bool:
@@ -198,8 +212,8 @@ class DiffusionEngine:
         self.cfg = cfg
         key = key if key is not None else jax.random.PRNGKey(0)
         k1, k2, k3 = jax.random.split(key, 3)
-        assert cfg.text.d_model == cfg.unet.context_dim, \
-            (cfg.text.d_model, cfg.unet.context_dim)
+        assert cfg.text.context_dim == cfg.unet.context_dim, \
+            (cfg.text.context_dim, cfg.unet.context_dim)
         # the denoiser contract resolves cfg.unet (ANY registered family
         # config — UNet or DiT) to its forward/init; everything below this
         # line is model-agnostic.  The attribute keeps its historical name:
@@ -521,7 +535,6 @@ class DiffusionEngine:
             bank = self._default_bank
         cfg = self.cfg
         s, c = cfg.unet.latent_size, cfg.unet.in_channels
-        ctx_shape = (num_slots, cfg.text.max_len, cfg.text.d_model)
         use_cfg = cfg.ddim.guidance_scale != 1.0
         if bank is not None:
             bank = solvers_mod.as_bank(bank)
@@ -532,8 +545,9 @@ class DiffusionEngine:
             # cond and uncond context must be DISTINCT buffers: the state
             # is donated to the admit/step executables, and XLA rejects
             # donating one buffer twice
-            context=jnp.zeros(ctx_shape),
-            uncond_context=jnp.zeros(ctx_shape) if use_cfg else None,
+            context=encoded_zeros(cfg.text, num_slots),
+            uncond_context=(encoded_zeros(cfg.text, num_slots) if use_cfg
+                            else None),
             step_idx=jnp.zeros((num_slots,), jnp.int32),
             active=jnp.zeros((num_slots,), bool),
             accum=LedgerAccum.zeros(num_buckets,
@@ -602,8 +616,8 @@ class DiffusionEngine:
             if latents is None:
                 latents = self.init_latents(1, key)
             return self._admit_compiled()(
-                state, jnp.int32(slot), ctx[0], latents[0],
-                None if un is None else un[0], jnp.int32(policy_index))
+                state, jnp.int32(slot), _row0(ctx), latents[0],
+                None if un is None else _row0(un), jnp.int32(policy_index))
 
     def _admit_compiled(self):
         if self._admit_fn is None:
@@ -614,13 +628,13 @@ class DiffusionEngine:
                 new = dataclasses.replace(
                     state,
                     latents=state.latents.at[slot].set(lat_row),
-                    context=state.context.at[slot].set(ctx_row),
+                    context=_set_row(state.context, slot, ctx_row),
                     step_idx=state.step_idx.at[slot].set(0),
                     active=state.active.at[slot].set(True))
                 if un_row is not None:
                     new = dataclasses.replace(
-                        new, uncond_context=state.uncond_context
-                        .at[slot].set(un_row))
+                        new, uncond_context=_set_row(state.uncond_context,
+                                                     slot, un_row))
                 if state.reuse_cache is not None:
                     # cache invalidation on admit: the row's first step
                     # must not reuse the previous occupant's activations

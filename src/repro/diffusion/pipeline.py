@@ -84,9 +84,9 @@ class StableDiffusionPipeline:
         self.cfg = cfg
         key = key if key is not None else jax.random.PRNGKey(0)
         k1, k2, k3 = jax.random.split(key, 3)
-        # context width must match: text d_model == unet context_dim
-        assert cfg.text.d_model == cfg.unet.context_dim, \
-            (cfg.text.d_model, cfg.unet.context_dim)
+        # context width must match: the towers' widths == unet context_dim
+        assert cfg.text.context_dim == cfg.unet.context_dim, \
+            (cfg.text.context_dim, cfg.unet.context_dim)
         self.denoiser = make_denoiser(cfg.unet)
         self.text_params = init_text_encoder_params(k1, cfg.text)
         self.unet_params = self.denoiser.init_params(k2)
@@ -360,7 +360,7 @@ def _terms_from_counters(cfg: "PipelineConfig", nnz, ones_xor, imp, rows,
     the same floats no matter what else shared the slot batch.
     """
     layers = attn_layer_order(cfg.unet)
-    heads = cfg.unet.num_heads
+    heads_at = getattr(cfg.unet, "heads_at", lambda res: cfg.unet.num_heads)
     per_iter_terms = []
     for i in range(start, start + n):
         sas_terms: dict = {}
@@ -373,7 +373,7 @@ def _terms_from_counters(cfg: "PipelineConfig", nnz, ones_xor, imp, rows,
             tq = res * res
             st = pssa.stats_from_counters(
                 jnp.asarray(int(nnz[i, li])), jnp.asarray(int(ones_xor[i, li])),
-                lead=r * heads, tq=tq, tk=tq,
+                lead=r * heads_at(res), tq=tq, tk=tq,
                 patch=cfg.unet.patch_size(res))
             num, den = sas_terms.get(res, (0.0, 0.0))
             sas_terms[res] = (num + float(st.bytes_pssa_total),

@@ -78,8 +78,14 @@ def cfg_batch(latents, context, uncond_context):
     fused call is arithmetically identical to two separate calls.
     """
     lat2 = jnp.concatenate([latents, latents], axis=0)
-    ctx2 = jnp.concatenate([context, uncond_context], axis=0)
-    return lat2, ctx2
+    return lat2, fuse_context(context, uncond_context)
+
+
+def fuse_context(context, uncond_context):
+    """[cond | uncond] rows of every leaf of the encoded text (a (B, T, d)
+    array, or a pytree of them such as SDXL's (states, pooled text))."""
+    return jax.tree.map(lambda c, u: jnp.concatenate([c, u], axis=0),
+                        context, uncond_context)
 
 
 def guided_eps(eps_fused, guidance_scale):
@@ -204,7 +210,7 @@ def denoise_step(unet_apply, latents, context, uncond_context, step_idx,
         # PSSA/TIPS accounted on the cond half only — the ledger never
         # consumes uncond stats (the two-call reference path computes
         # and discards them; the fused path skips them).
-        ctx_fused = jnp.concatenate([context, uncond_context], axis=0)
+        ctx_fused = fuse_context(context, uncond_context)
         rows = b if stats_rows is None else stats_rows
         out = unet_apply(latents, t, ctx_fused, tips_vec,
                          stats_rows=rows, cfg_dup=True, **kw)

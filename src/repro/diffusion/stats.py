@@ -62,22 +62,34 @@ def attn_layer_order(cfg) -> Tuple[LayerKey, ...]:
 def _unet_attn_layer_order(cfg) -> Tuple[LayerKey, ...]:
     """UNet traversal: down stages (attn at ``latent >> i``), optional mid
     block, then up stages (stage ``j`` revisits resolution
-    ``latent >> rev[j]``) — mirrors ``unet_forward`` exactly.
+    ``latent >> rev[j]``) — mirrors ``unet_forward`` exactly.  A stack of
+    depth 1 is one key named after its resnet (``down1.0``); a deeper one
+    has a key per basic block (``down2.0.0`` ... ``down2.0.9``), and the
+    mid block takes the last stage's depth.
     """
     order = []
     nstages = len(cfg.block_channels)
+    depth = getattr(cfg, "transformer_depth", 1)
+
+    def stack(tag, stage):
+        d = depth if isinstance(depth, int) else depth[stage]
+        res = cfg.latent_size >> stage
+        if d == 1:
+            return [LayerKey(tag, res)]
+        return [LayerKey(f"{tag}.{k}", res) for k in range(d)]
+
     for i, has_attn in enumerate(cfg.down_attn):
         if not has_attn:
             continue
         for r in range(cfg.resnets_per_down):
-            order.append(LayerKey(f"down{i}.{r}", cfg.latent_size >> i))
+            order += stack(f"down{i}.{r}", i)
     if cfg.has_mid_block:
-        order.append(LayerKey("mid", cfg.latent_size >> (nstages - 1)))
+        order += stack("mid", nstages - 1)
     for j, i in enumerate(reversed(range(nstages))):
         if not cfg.down_attn[i]:
             continue
         for r in range(cfg.resnets_per_up):
-            order.append(LayerKey(f"up{j}.{r}", cfg.latent_size >> i))
+            order += stack(f"up{j}.{r}", i)
     return tuple(order)
 
 

@@ -1,20 +1,27 @@
 """BK-SDM-style UNet (paper's workload) with PSSA / TIPS / DBSC folded in.
 
-Architecturally compressed Stable-Diffusion UNet following BK-SDM-Tiny
-(Kim et al., 2023 — the paper's evaluation network): SD-v1 block layout with
-one resnet + one transformer block per down stage, two per up stage, and the
-mid-block removed.  Each block is
+Architecturally compressed Stable-Diffusion UNet following BK-SDM (Kim et
+al., 2023 — the paper's evaluation network): SD-v1 block layout with one
+resnet + one transformer stack per down stage, two per up stage, and the
+mid-block removed.  The default geometry is BK-SDM-Small's (482.3M
+parameters); BK-SDM-Tiny (0.32B) also drops the innermost stage pair.  The
+same module serves SDXL base (``configs.sdxl_base``): a transformer depth
+and head count per stage, a mid block, and the "text_time" added
+conditioning.  Each block is
 
     CNN stage          — two 3x3 convs (resnet, GroupNorm + SiLU, time-embed
                          FiLM add), input-stationary on the DBSC;
-    transformer stage  — self-attention (pixel-wise; PSSA prunes + compresses
+    transformer stage  — GroupNorm + proj_in, ``transformer_depth`` basic
+                         blocks, proj_out + residual (SD's Transformer2DModel
+                         with a linear projection).  A basic block is
+                         self-attention (pixel-wise; PSSA prunes + compresses
                          the score matrix on its way to DRAM), cross-attention
                          over the text keys (emits the CLS attention score
                          that TIPS thresholds), and a GEGLU FFN whose rows run
                          INT12/INT6 mixed-precision per the TIPS mask.
 
 The module is pure JAX and runs at reduced size on CPU (tests/examples); the
-full BK-SDM-Tiny geometry is exercised analytically by ``diffusion.ledger``
+full BK-SDM geometry is exercised analytically by ``diffusion.ledger``
 (bytes/MACs) and by shape-level ``jax.eval_shape`` checks — matching how the
 paper itself evaluates (energy / EMA / throughput, not accuracy).
 
@@ -46,21 +53,36 @@ from repro.kernels.patch_reuse import ops as reuse_ops
 
 @dataclasses.dataclass(frozen=True)
 class UNetConfig:
+    # the defaults are BK-SDM-Small's geometry (482.3M parameters), which
+    # the program has long called BK-SDM-Tiny; Tiny (0.32B) also drops the
+    # innermost 1280-channel stage and its up stage
     in_channels: int = 4
     out_channels: int = 4
     block_channels: tuple = (320, 640, 1280, 1280)
     down_attn: tuple = (True, True, True, False)
-    resnets_per_down: int = 1          # BK-SDM-Tiny: 1 (base SD: 2)
-    resnets_per_up: int = 2            # BK-SDM-Tiny: 2 (base SD: 3)
+    resnets_per_down: int = 1          # BK-SDM-Small/Tiny: 1 (base SD: 2)
+    resnets_per_up: int = 2            # BK-SDM-Small/Tiny: 2 (base SD: 3)
     has_mid_block: bool = False        # removed in BK-SDM-Small/Tiny
-    transformer_depth: int = 1
-    num_heads: int = 8
+    # basic blocks per transformer stack and attention heads: one int for
+    # every stage, or a tuple with one entry per stage (SDXL: depth
+    # (1, 2, 10), heads (5, 10, 20)); the mid block takes the last stage's
+    transformer_depth: int | tuple = 1
+    num_heads: int | tuple = 8
     context_dim: int = 768             # CLIP ViT-L/14 text width
     text_len: int = 77
     time_dim: int = 1280
     latent_size: int = 64              # 512x512 images -> 64x64x4 latents
     groups: int = 32
     ffn_mult: int = 4                  # GEGLU hidden = 4 * channels
+    # SDXL's added conditioning: "text_time" takes the context as
+    # (text states, pooled text) and adds MLP([pooled, sinusoid of each of
+    # the six size/crop ids]) to the time embedding; "none" reads only
+    # the text states
+    addition_embed: str = "none"       # "none" | "text_time"
+    addition_time_dim: int = 256
+    pooled_dim: int = 1280
+    # original (h, w), crop (top, left), target (h, w)
+    addition_time_ids: tuple = (1024, 1024, 0, 0, 1024, 1024)
 
     # --- paper features ---
     pssa: bool = True
@@ -92,6 +114,15 @@ class UNetConfig:
     dtype: str = "float32"
 
     def __post_init__(self):
+        for name in ("transformer_depth", "num_heads"):
+            v = getattr(self, name)
+            if not isinstance(v, int) and len(v) != len(self.block_channels):
+                raise ValueError(f"UNetConfig.{name}={v!r}: one entry per "
+                                 f"stage of {self.block_channels}")
+        if self.addition_embed not in ("none", "text_time"):
+            raise ValueError(f"UNetConfig.addition_embed="
+                             f"{self.addition_embed!r}: 'none' or "
+                             f"'text_time'")
         # the legacy fold-in knobs are deprecated aliases of the policy
         # objects (DESIGN.md §13): warn at the spelling site — the
         # construction that sets a non-default value — not in the
@@ -114,6 +145,21 @@ class UNetConfig:
                 "precision=PrecisionPolicy(threshold=...) (or "
                 "ServePolicies(precision=...)); the cache key and ledger "
                 "are identical either way")
+
+    def stage_depth(self, stage: int) -> int:
+        """Basic blocks in stage ``stage``'s transformer stacks."""
+        d = self.transformer_depth
+        return d if isinstance(d, int) else d[stage]
+
+    def stage_heads(self, stage: int) -> int:
+        """Attention heads of stage ``stage``'s transformer blocks."""
+        h = self.num_heads
+        return h if isinstance(h, int) else h[stage]
+
+    def heads_at(self, resolution: int) -> int:
+        """Attention heads of the blocks at a feature-map resolution."""
+        return self.stage_heads(
+            (self.latent_size // resolution).bit_length() - 1)
 
     def patch_size(self, resolution: int) -> int:
         """PSXU patch width at a given feature-map resolution (16/32/64)."""
@@ -179,20 +225,21 @@ class UNetConfig:
 
     @property
     def num_down_attn_layers(self) -> int:
-        return sum(self.resnets_per_down * self.transformer_depth
-                   for a in self.down_attn if a)
+        return sum(self.resnets_per_down * self.stage_depth(i)
+                   for i, a in enumerate(self.down_attn) if a)
 
 
-BK_SDM_TINY = UNetConfig()
+BK_SDM_TINY = UNetConfig()           # BK-SDM-Small's geometry (see above)
 
 
 # ----------------------------------------------------------------------------
 # Primitive layers
 # ----------------------------------------------------------------------------
 def conv2d(x, w, b=None, stride: int = 1, padding: int = 1):
-    """NHWC conv with HWIO weights."""
+    """NHWC conv with HWIO weights (cast to ``x``'s type: weights may be
+    stored narrower than the activations)."""
     y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(stride, stride),
+        x, w.astype(x.dtype), window_strides=(stride, stride),
         padding=((padding, padding), (padding, padding)),
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
     if b is not None:
@@ -264,27 +311,39 @@ def _resnet_p(key, cin, cout, tdim, dtype):
     return p
 
 
-def _transformer_p(key, c, cfg: UNetConfig, dtype):
-    ks = jax.random.split(key, 12)
+def _basic_block_p(ks, c, cfg: UNetConfig, dtype):
+    """One basic block (self-attn, cross-attn, GEGLU FFN) from 10 keys."""
     dff = cfg.ffn_mult * c
     return {
-        "norm_in": _norm_p(c, dtype),
-        "proj_in": _lin_p(ks[0], c, c, dtype),
         "ln1": _norm_p(c, dtype),
-        "sa_q": _lin_p(ks[1], c, c, dtype, bias=False),
-        "sa_k": _lin_p(ks[2], c, c, dtype, bias=False),
-        "sa_v": _lin_p(ks[3], c, c, dtype, bias=False),
-        "sa_o": _lin_p(ks[4], c, c, dtype),
+        "sa_q": _lin_p(ks[0], c, c, dtype, bias=False),
+        "sa_k": _lin_p(ks[1], c, c, dtype, bias=False),
+        "sa_v": _lin_p(ks[2], c, c, dtype, bias=False),
+        "sa_o": _lin_p(ks[3], c, c, dtype),
         "ln2": _norm_p(c, dtype),
-        "ca_q": _lin_p(ks[5], c, c, dtype, bias=False),
-        "ca_k": _lin_p(ks[6], cfg.context_dim, c, dtype, bias=False),
-        "ca_v": _lin_p(ks[7], cfg.context_dim, c, dtype, bias=False),
-        "ca_o": _lin_p(ks[8], c, c, dtype),
+        "ca_q": _lin_p(ks[4], c, c, dtype, bias=False),
+        "ca_k": _lin_p(ks[5], cfg.context_dim, c, dtype, bias=False),
+        "ca_v": _lin_p(ks[6], cfg.context_dim, c, dtype, bias=False),
+        "ca_o": _lin_p(ks[7], c, c, dtype),
         "ln3": _norm_p(c, dtype),
-        "ff_geglu": _lin_p(ks[9], c, 2 * dff, dtype),
-        "ff_out": _lin_p(ks[10], dff, c, dtype),
-        "proj_out": _lin_p(ks[11], c, c, dtype),
+        "ff_geglu": _lin_p(ks[8], c, 2 * dff, dtype),
+        "ff_out": _lin_p(ks[9], dff, c, dtype),
     }
+
+
+def _transformer_p(key, c, cfg: UNetConfig, dtype, depth: int = 1):
+    """A transformer stack: GroupNorm, proj_in, ``depth`` basic blocks,
+    proj_out.  A depth-1 stack holds its block's weights beside its own;
+    a deeper one lists its blocks under ``blocks``."""
+    ks = jax.random.split(key, 12)
+    p = {"norm_in": _norm_p(c, dtype),
+         "proj_in": _lin_p(ks[0], c, c, dtype),
+         "proj_out": _lin_p(ks[11], c, c, dtype)}
+    if depth == 1:
+        return {**p, **_basic_block_p(ks[1:11], c, cfg, dtype)}
+    p["blocks"] = [_basic_block_p(jax.random.split(k, 10), c, cfg, dtype)
+                   for k in jax.random.split(ks[1], depth)]
+    return p
 
 
 def init_unet_params(key, cfg: UNetConfig):
@@ -296,6 +355,11 @@ def init_unet_params(key, cfg: UNetConfig):
         "time_mlp2": _lin_p(next(keys), cfg.time_dim, cfg.time_dim, dtype),
         "conv_in": _conv_p(next(keys), 3, 3, cfg.in_channels, chans[0], dtype),
     }
+    if cfg.addition_embed == "text_time":
+        add_in = cfg.pooled_dim + (len(cfg.addition_time_ids)
+                                   * cfg.addition_time_dim)
+        p["add_mlp1"] = _lin_p(next(keys), add_in, cfg.time_dim, dtype)
+        p["add_mlp2"] = _lin_p(next(keys), cfg.time_dim, cfg.time_dim, dtype)
     # --- down path (track the skip-channel stack exactly as forward pushes) ---
     down = []
     skip_channels = [chans[0]]          # conv_in output
@@ -306,8 +370,8 @@ def init_unet_params(key, cfg: UNetConfig):
             stage["resnets"].append(
                 _resnet_p(next(keys), cin, cout, cfg.time_dim, dtype))
             if cfg.down_attn[i]:
-                stage["attns"].append(
-                    _transformer_p(next(keys), cout, cfg, dtype))
+                stage["attns"].append(_transformer_p(
+                    next(keys), cout, cfg, dtype, cfg.stage_depth(i)))
             cin = cout
             skip_channels.append(cout)
         if i < len(chans) - 1:
@@ -320,7 +384,8 @@ def init_unet_params(key, cfg: UNetConfig):
         c = chans[-1]
         p["mid"] = {
             "res1": _resnet_p(next(keys), c, c, cfg.time_dim, dtype),
-            "attn": _transformer_p(next(keys), c, cfg, dtype),
+            "attn": _transformer_p(next(keys), c, cfg, dtype,
+                                   cfg.stage_depth(len(chans) - 1)),
             "res2": _resnet_p(next(keys), c, c, cfg.time_dim, dtype),
         }
 
@@ -337,8 +402,8 @@ def init_unet_params(key, cfg: UNetConfig):
             stage["resnets"].append(_resnet_p(
                 next(keys), cin + skip_c, cout, cfg.time_dim, dtype))
             if cfg.down_attn[i]:
-                stage["attns"].append(
-                    _transformer_p(next(keys), cout, cfg, dtype))
+                stage["attns"].append(_transformer_p(
+                    next(keys), cout, cfg, dtype, cfg.stage_depth(i)))
             cin = cout
         if j < len(chans) - 1:
             stage["up"] = _conv_p(next(keys), 3, 3, cout, cout, dtype)
@@ -384,8 +449,14 @@ def _transformer_block(x2d, p, context, cfg: UNetConfig, tips_active,
                        policy: KernelPolicy | None = None,
                        precision: PrecisionPolicy | None = None,
                        row_stats: bool = False, reuse=None,
-                       overrides=None, modulation=None):
-    """x2d: (B, H, W, C) -> (out, PSSAStats, TIPSResult, reuse_out).
+                       overrides=None, modulation=None, heads=None):
+    """One transformer stack, x2d: (B, H, W, C) ->
+    (out, (PSSAStats, ...), (TIPSResult, ...), reuse_out).
+
+    The stack is GroupNorm + ``proj_in``, its basic blocks (one for the
+    flat depth-1 layout, else ``p["blocks"]``), ``proj_out`` and the
+    residual; the stats tuples hold one entry per basic block.  ``heads``
+    is the blocks' head count (None: ``cfg.num_heads``).
 
     ``tips_active`` is a scalar flag (whole-batch schedule) or a (B,) row
     vector — continuous batching runs slots at heterogeneous denoising
@@ -404,11 +475,11 @@ def _transformer_block(x2d, p, context, cfg: UNetConfig, tips_active,
     ``dup_after_self``: CFG prefix deduplication.  Under fused CFG, the
     cond and uncond halves are IDENTICAL until the first cross-attention
     (only the text context differs), so the fused path runs everything up
-    to and including this block's self-attention on the cond half alone
-    and tiles the hidden state to both halves here — exact, and it halves
-    the most expensive self-attention in the network (the first block sits
-    at the highest resolution).  ``x2d`` then has half as many rows as
-    ``context``.
+    to and including this stack's first self-attention on the cond half
+    alone and tiles the hidden state to both halves there — exact, and it
+    halves the most expensive self-attention in the network (the first
+    block sits at the highest resolution).  ``x2d`` then has half as many
+    rows as ``context``.
 
     ``reuse``: ``None`` (dense path, ``reuse_out`` is None) or a
     ``(ReusePolicy, LayerReuseCache, valid)`` triple.  With it, the block
@@ -440,7 +511,9 @@ def _transformer_block(x2d, p, context, cfg: UNetConfig, tips_active,
     """
     b, hgt, wid, c = x2d.shape
     res = hgt  # feature-map resolution
-    heads = cfg.num_heads
+    if heads is None:
+        heads = cfg.num_heads
+    blocks = p["blocks"] if "blocks" in p else (p,)
     if policy is None:
         policy = cfg.effective_kernel_policy()
     if precision is None:
@@ -503,114 +576,141 @@ def _transformer_block(x2d, p, context, cfg: UNetConfig, tips_active,
                    cfg.groups)
     h = h.reshape(b, hgt * wid, c)
     h = jnp.einsum("btc,cd->btd", h, p["proj_in"]["w"]) + p["proj_in"]["b"]
-    resid = h
+    sa_stats, tips_results = [], []
+    for n, bp in enumerate(blocks):
+        dup_here = dup_after_self and n == 0
+        resid = h
 
-    # --- self-attention (PSSA) ---
-    hn = layer_norm(h, p["ln1"]["scale"], p["ln1"]["bias"])
-    if modulation is not None:
-        hn = hn * (1.0 + _per_rows(modulation[1], hn.shape[0])) \
-            + _per_rows(modulation[0], hn.shape[0])
-    # reuse: queries gathered to the active patch rows, K/V stay dense —
-    # every gathered query still attends over the full token set
-    hn_q = hn if reuse is None else reuse_ops.gather_rows(hn, rows)
-    q = _attn_heads(hn_q, p["sa_q"]["w"], heads)
-    k = _attn_heads(hn, p["sa_k"]["w"], heads)
-    v = _attn_heads(hn, p["sa_v"]["w"], heads)
-    patch = cfg.patch_size(res)
-    sa_threshold = cfg.pssa_threshold
-    if overrides is not None and overrides.pssa_scale is not None:
-        # self-attention runs on the cond half pre-dup (b == request
-        # rows) and on [cond | uncond] in post-dup blocks — tile to match
-        sa_threshold = cfg.pssa_threshold * _per_rows(
-            overrides.pssa_scale, q.shape[0])
-    sa = dispatch.self_attention(policy, q, k, v, patch=patch,
-                                 threshold=sa_threshold,
-                                 prune_scores=cfg.pssa,
-                                 stats_rows=None if dup_after_self
-                                 else stats_rows,
-                                 reference_stats=cfg.pssa_stats_reference,
-                                 row_stats=row_stats)
-    sa_proj = jnp.einsum("btd,dc->btc", _merge_heads(sa.out),
-                         p["sa_o"]["w"]) + p["sa_o"]["b"]
-    if modulation is not None:
-        sa_proj = sa_proj * _per_rows(modulation[2], sa_proj.shape[0])
-    if reuse is not None:
-        sa_proj = reuse_ops.scatter_rows(cache.sa, rows, sa_proj, gate_rows)
-    sa_full = sa_proj
-    h = resid + sa_proj
-
-    if dup_after_self:
-        # tile [cond] -> [cond | uncond]; divergence starts at cross-attn
-        h = jnp.concatenate([h, h], axis=0)
-        x2d = jnp.concatenate([x2d, x2d], axis=0)
-        b = x2d.shape[0]
+        # --- self-attention (PSSA) ---
+        hn = layer_norm(h, bp["ln1"]["scale"], bp["ln1"]["bias"])
+        if modulation is not None:
+            hn = hn * (1.0 + _per_rows(modulation[1], hn.shape[0])) \
+                + _per_rows(modulation[0], hn.shape[0])
+        # reuse: queries gathered to the active patch rows, K/V stay
+        # dense — every gathered query still attends over the full tokens
+        hn_q = hn if reuse is None else reuse_ops.gather_rows(hn, rows)
+        q = _attn_heads(hn_q, bp["sa_q"]["w"], heads)
+        k = _attn_heads(hn, bp["sa_k"]["w"], heads)
+        v = _attn_heads(hn, bp["sa_v"]["w"], heads)
+        patch = cfg.patch_size(res)
+        sa_threshold = cfg.pssa_threshold
+        if overrides is not None and overrides.pssa_scale is not None:
+            # self-attention runs on the cond half pre-dup (b == request
+            # rows) and on [cond | uncond] post-dup — tile to match
+            sa_threshold = cfg.pssa_threshold * _per_rows(
+                overrides.pssa_scale, q.shape[0])
+        sa = dispatch.self_attention(policy, q, k, v, patch=patch,
+                                     threshold=sa_threshold,
+                                     prune_scores=cfg.pssa,
+                                     stats_rows=None if dup_here
+                                     else stats_rows,
+                                     reference_stats=(
+                                         cfg.pssa_stats_reference),
+                                     row_stats=row_stats)
+        sa_proj = jnp.einsum("btd,dc->btc", _merge_heads(sa.out),
+                             bp["sa_o"]["w"]) + bp["sa_o"]["b"]
+        if modulation is not None:
+            sa_proj = sa_proj * _per_rows(modulation[2], sa_proj.shape[0])
         if reuse is not None:
-            # the plan was computed on the cond half; both halves share it
-            rows = jnp.concatenate([rows, rows], axis=0)
-            gate_rows = jnp.concatenate([gate_rows, gate_rows], axis=0)
+            sa_proj = reuse_ops.scatter_rows(cache.sa, rows, sa_proj,
+                                             gate_rows)
+        sa_full = sa_proj
+        h = resid + sa_proj
 
-    # --- cross-attention (TIPS CAS source) ---
-    resid = h
-    hn = layer_norm(h, p["ln2"]["scale"], p["ln2"]["bias"])
-    if modulation is not None:
-        hn = hn * (1.0 + _per_rows(modulation[4], hn.shape[0])) \
-            + _per_rows(modulation[3], hn.shape[0])
-    hn_q = hn if reuse is None else reuse_ops.gather_rows(hn, rows)
-    q = _attn_heads(hn_q, p["ca_q"]["w"], heads)
-    kt = _attn_heads(context, p["ca_k"]["w"], heads)
-    vt = _attn_heads(context, p["ca_v"]["w"], heads)
-    tips_scale = (None if overrides is None
-                  else _per_rows(overrides.tips_scale, h.shape[0]))
-    ca = dispatch.cross_attention(policy, q, kt, vt, precision=precision,
-                                  stats_rows=stats_rows,
-                                  row_stats=row_stats,
-                                  threshold_scale=tips_scale)
-    ca_proj = jnp.einsum("btd,dc->btc", _merge_heads(ca.out),
-                         p["ca_o"]["w"]) + p["ca_o"]["b"]
-    if modulation is not None:
-        ca_proj = ca_proj * _per_rows(modulation[5], ca_proj.shape[0])
-    if reuse is not None:
-        ca_proj = reuse_ops.scatter_rows(cache.ca, rows, ca_proj, gate_rows)
-    ca_full = ca_proj
-    h = resid + ca_proj
+        if dup_here:
+            # tile [cond] -> [cond | uncond]; divergence starts at the
+            # cross-attention
+            h = jnp.concatenate([h, h], axis=0)
+            x2d = jnp.concatenate([x2d, x2d], axis=0)
+            b = x2d.shape[0]
+            if reuse is not None:
+                # the plan was computed on the cond half; both halves
+                # share it
+                rows = jnp.concatenate([rows, rows], axis=0)
+                gate_rows = jnp.concatenate([gate_rows, gate_rows], axis=0)
 
-    # --- FFN (GEGLU) with TIPS mixed precision ---
-    resid = h
-    hn = layer_norm(h, p["ln3"]["scale"], p["ln3"]["bias"])
-    if modulation is not None:
-        hn = hn * (1.0 + _per_rows(modulation[7], hn.shape[0])) \
-            + _per_rows(modulation[6], hn.shape[0])
-    hn_f = hn if reuse is None else reuse_ops.gather_rows(hn, rows)
-    if cfg.tips:
-        active = tips_active
-        if getattr(active, "ndim", 0) == 1:
-            # per-row activity (continuous batching): broadcast over tokens;
-            # under cfg_dup the rows doubled at the cross-attn, tile to match
-            if active.shape[0] != h.shape[0]:
-                active = jnp.concatenate([active, active], axis=0)
-            active = active[:, None]
-        # ca.important_full already lives on the gathered rows (the
-        # cross-attention queries were gathered with the same plan)
-        important = jnp.logical_or(ca.important_full,
-                                   jnp.logical_not(active))
-    else:
-        important = None
-    ffn = dispatch.ffn_geglu(policy, hn_f, p, important,
-                             precision=precision)
-    if modulation is not None:
-        ffn = ffn * _per_rows(modulation[8], ffn.shape[0])
-    if reuse is not None:
-        ffn = reuse_ops.scatter_rows(cache.ffn, rows, ffn, gate_rows)
-    ffn_full = ffn
-    h = resid + ffn
+        # --- cross-attention (TIPS CAS source) ---
+        resid = h
+        hn = layer_norm(h, bp["ln2"]["scale"], bp["ln2"]["bias"])
+        if modulation is not None:
+            hn = hn * (1.0 + _per_rows(modulation[4], hn.shape[0])) \
+                + _per_rows(modulation[3], hn.shape[0])
+        hn_q = hn if reuse is None else reuse_ops.gather_rows(hn, rows)
+        q = _attn_heads(hn_q, bp["ca_q"]["w"], heads)
+        kt = _attn_heads(context, bp["ca_k"]["w"], heads)
+        vt = _attn_heads(context, bp["ca_v"]["w"], heads)
+        tips_scale = (None if overrides is None
+                      else _per_rows(overrides.tips_scale, h.shape[0]))
+        ca = dispatch.cross_attention(policy, q, kt, vt,
+                                      precision=precision,
+                                      stats_rows=stats_rows,
+                                      row_stats=row_stats,
+                                      threshold_scale=tips_scale)
+        ca_proj = jnp.einsum("btd,dc->btc", _merge_heads(ca.out),
+                             bp["ca_o"]["w"]) + bp["ca_o"]["b"]
+        if modulation is not None:
+            ca_proj = ca_proj * _per_rows(modulation[5], ca_proj.shape[0])
+        if reuse is not None:
+            ca_proj = reuse_ops.scatter_rows(cache.ca, rows, ca_proj,
+                                             gate_rows)
+        ca_full = ca_proj
+        h = resid + ca_proj
+
+        # --- FFN (GEGLU) with TIPS mixed precision ---
+        resid = h
+        hn = layer_norm(h, bp["ln3"]["scale"], bp["ln3"]["bias"])
+        if modulation is not None:
+            hn = hn * (1.0 + _per_rows(modulation[7], hn.shape[0])) \
+                + _per_rows(modulation[6], hn.shape[0])
+        hn_f = hn if reuse is None else reuse_ops.gather_rows(hn, rows)
+        if cfg.tips:
+            active = tips_active
+            if getattr(active, "ndim", 0) == 1:
+                # per-row activity (continuous batching): broadcast over
+                # tokens; under cfg_dup the rows doubled at the
+                # cross-attention, tile to match
+                if active.shape[0] != h.shape[0]:
+                    active = jnp.concatenate([active, active], axis=0)
+                active = active[:, None]
+            # ca.important_full already lives on the gathered rows (the
+            # cross-attention queries were gathered with the same plan)
+            important = jnp.logical_or(ca.important_full,
+                                       jnp.logical_not(active))
+        else:
+            important = None
+        ffn = dispatch.ffn_geglu(policy, hn_f, bp, important,
+                                 precision=precision)
+        if modulation is not None:
+            ffn = ffn * _per_rows(modulation[8], ffn.shape[0])
+        if reuse is not None:
+            ffn = reuse_ops.scatter_rows(cache.ffn, rows, ffn, gate_rows)
+        ffn_full = ffn
+        h = resid + ffn
+        sa_stats.append(sa.stats)
+        tips_results.append(ca.tips_result)
 
     h = jnp.einsum("btc,cd->btd", h, p["proj_out"]["w"]) + p["proj_out"]["b"]
     out = x2d + h.reshape(b, hgt, wid, c)
+    sa_stats, tips_results = tuple(sa_stats), tuple(tips_results)
     if reuse is None:
-        return out, sa.stats, ca.tips_result, None
+        return out, sa_stats, tips_results, None
     new_cache = LayerReuseCache(ref=tokens_in, sa=sa_full, ca=ca_full,
                                 ffn=ffn_full)
-    return out, sa.stats, ca.tips_result, (new_cache, counters)
+    return out, sa_stats, tips_results, (new_cache, counters)
+
+
+def _added_embedding(params, pooled, cfg: UNetConfig):
+    """SDXL's "text_time" embedding: MLP([pooled text, the sinusoid of each
+    size/crop id]), (rows, pooled_dim) -> (rows, time_dim)."""
+    ids = timestep_embedding(jnp.asarray(cfg.addition_time_ids),
+                             cfg.addition_time_dim).reshape(1, -1)
+    x = jnp.concatenate(
+        [pooled, jnp.broadcast_to(ids, (pooled.shape[0], ids.shape[1]))],
+        axis=-1)
+    x = jnp.einsum("bd,dc->bc", x, params["add_mlp1"]["w"]) \
+        + params["add_mlp1"]["b"]
+    return jnp.einsum("bd,dc->bc", jax.nn.silu(x),
+                      params["add_mlp2"]["w"]) + params["add_mlp2"]["b"]
 
 
 def _downsample(x, p):
@@ -635,6 +735,9 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
                  overrides=None):
     """latents (B, S, S, 4), timesteps (B,), context (B, Ttext, ctx_dim).
 
+    Under ``cfg.addition_embed == "text_time"`` the context is the pair
+    (text states (B, Ttext, ctx_dim), pooled text (B, pooled_dim)).
+
     Returns (eps-prediction (B, S, S, 4), ``UNetStats`` pytree) with one
     PSSA/TIPS entry per transformer block in ``attn_layer_order(cfg)``.
     ``stats_rows`` (static) restricts stats to the first N batch rows; the
@@ -649,9 +752,11 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
 
     ``cfg_dup``: fused-CFG prefix deduplication.  ``latents``/``timesteps``
     carry ONLY the cond half (B rows) while ``context`` carries
-    ``[cond | uncond]`` (2B rows); everything up to the first
-    cross-attention — identical for both halves — runs once on B rows and
-    the hidden state is tiled to 2B there.  ``eps`` comes back with 2B
+    ``[cond | uncond]`` (2B rows); everything before the first operation
+    that reads text — identical for both halves — runs once on B rows and
+    the hidden state is tiled to 2B there: the first cross-attention, or
+    under "text_time" (whose time embedding holds the pooled text) the
+    first resnet, right after ``conv_in``.  ``eps`` comes back with 2B
     rows, split by ``sampler.guided_eps``.
 
     ``reuse_cache`` (a ``core.reuse.ReuseCache`` built for this batch/CFG
@@ -677,17 +782,28 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
     reuse_pol = cfg.reuse_policy
     reuse_on = reuse_pol.enabled and reuse_cache is not None
     needs_dup = cfg_dup
+    text_time = cfg.addition_embed == "text_time"
+    if text_time:
+        context, pooled = context
     if cfg_dup:
         assert context.shape[0] == 2 * latents.shape[0], \
             (context.shape, latents.shape)
+    if reuse_on and (text_time or any(
+            cfg.stage_depth(i) > 1 for i in range(len(cfg.block_channels)))):
+        raise ValueError("temporal patch reuse serves depth-1 transformer "
+                         "stacks without added conditioning only")
 
     temb = timestep_embedding(timesteps, cfg.block_channels[0])
     temb = jnp.einsum("bd,dc->bc", temb, params["time_mlp1"]["w"]) \
         + params["time_mlp1"]["b"]
     temb = jnp.einsum("bd,dc->bc", jax.nn.silu(temb),
                       params["time_mlp2"]["w"]) + params["time_mlp2"]["b"]
+    if text_time:
+        if cfg_dup:
+            temb = jnp.concatenate([temb, temb], axis=0)
+        temb = temb + _added_embedding(params, pooled, cfg)
 
-    def attn_block(h, bp):
+    def attn_block(h, bp, stage):
         nonlocal temb, needs_dup
         reuse_arg = None
         if reuse_on:
@@ -700,13 +816,14 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
                                            precision=precision,
                                            row_stats=row_stats,
                                            reuse=reuse_arg,
-                                           overrides=overrides)
+                                           overrides=overrides,
+                                           heads=cfg.stage_heads(stage))
         if needs_dup:
             # downstream resnets now see [cond | uncond] rows
             temb = jnp.concatenate([temb, temb], axis=0)
             needs_dup = False
-        pssa_stats.append(sa)
-        tips_stats.append(ca)
+        pssa_stats.extend(sa)
+        tips_stats.extend(ca)
         if reuse_on:
             new_layer_caches.append(ru[0])
             reuse_stats.append(ru[1])
@@ -719,13 +836,19 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
         return skip
 
     h = conv2d(latents, params["conv_in"]["w"], params["conv_in"]["b"])
+    if needs_dup and text_time:
+        # the pooled text is in the time embedding: the halves differ from
+        # the first resnet on
+        h = jnp.concatenate([h, h], axis=0)
+        needs_dup = False
     skips = [h]
 
+    last = len(cfg.block_channels) - 1
     for i, stage in enumerate(params["down"]):
         for r, rp in enumerate(stage["resnets"]):
             h = _resnet(h, rp, temb, cfg.groups)
             if stage["attns"]:
-                h = attn_block(h, stage["attns"][r])
+                h = attn_block(h, stage["attns"][r], i)
             skips.append(h)
         if "down" in stage:
             h = _downsample(h, stage["down"])
@@ -734,7 +857,7 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
     if cfg.has_mid_block:
         mp = params["mid"]
         h = _resnet(h, mp["res1"], temb, cfg.groups)
-        h = attn_block(h, mp["attn"])
+        h = attn_block(h, mp["attn"], last)
         h = _resnet(h, mp["res2"], temb, cfg.groups)
 
     for j, stage in enumerate(params["up"]):
@@ -743,7 +866,7 @@ def unet_forward(params, latents, timesteps, context, cfg: UNetConfig,
             h = _resnet(jnp.concatenate([h, skip], axis=-1), rp, temb,
                         cfg.groups)
             if stage["attns"]:
-                h = attn_block(h, stage["attns"][r])
+                h = attn_block(h, stage["attns"][r], last - j)
         if "up" in stage:
             h = _upsample(h, stage["up"])
 

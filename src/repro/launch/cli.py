@@ -29,11 +29,14 @@ def add_policy_args(ap, tiers: bool = True):
     ``tiers=False`` omits ``--tiers`` for single-request CLIs (a bank is
     meaningless when exactly one request is in flight).  Returns ``ap``.
     """
-    ap.add_argument("--model", choices=("unet", "dit"), default="unet",
-                    help="denoiser family (DESIGN.md §11): the BK-SDM "
-                         "UNet (default) or the DiT-S/2 transformer; both "
-                         "serve through the same engine/scheduler spine "
-                         "and kernel dispatch table")
+    ap.add_argument("--model", choices=("unet", "dit", "sdxl"),
+                    default="unet",
+                    help="denoiser (DESIGN.md §11): the BK-SDM UNet "
+                         "(default), the DiT-S/2 transformer, or SDXL "
+                         "base 1.0 (repro.configs.sdxl_base: its UNet, "
+                         "two text towers and VAE); all serve through "
+                         "the same engine/scheduler spine and kernel "
+                         "dispatch table")
     ap.add_argument("--kernels", default="auto",
                     help="kernel policy: 'auto' (fused on compiled "
                          "backends, reference on interpret backends), "
@@ -93,8 +96,9 @@ def config_from_args(args, policies=None, steps=None, guidance=None):
     """Build the ``PipelineConfig`` a CLI run serves.
 
     Geometry from ``--smoke`` (absent = smoke, the CLI-demo default),
-    denoiser family from ``--model``, schedule from ``--steps`` /
-    ``--guidance`` (overridable via the keyword args), and the policy
+    denoiser family from ``--model`` (``sdxl``: the whole SDXL preset),
+    schedule from ``--steps`` / ``--guidance`` (overridable via the
+    keyword args), and the policy
     bundle's kernel/precision/reuse axes installed via
     ``ServePolicies.apply``.  ``policies=None`` parses the bundle from
     ``args`` (:func:`policies_from_args`).
@@ -104,7 +108,10 @@ def config_from_args(args, policies=None, steps=None, guidance=None):
 
     smoke = getattr(args, "smoke", True)
     cfg = PipelineConfig.smoke() if smoke else PipelineConfig()
-    if getattr(args, "model", "unet") == "dit":
+    if getattr(args, "model", "unet") == "sdxl":
+        from repro.configs import sdxl_base
+        cfg = sdxl_base.SMOKE if smoke else sdxl_base.CONFIG
+    elif getattr(args, "model", "unet") == "dit":
         # swap the denoiser family; the engine/sampler/serving spine is
         # family-agnostic through the denoiser contract (DESIGN.md §11)
         from repro.diffusion.dit import DiTConfig

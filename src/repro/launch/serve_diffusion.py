@@ -2,7 +2,7 @@
 
   PYTHONPATH=src python -m repro.launch.serve_diffusion --smoke \
       --requests 8 --micro-batch 4 --steps 5 [--guidance 7.5] \
-      [--model unet|dit] [--kernels fused] [--tips adaptive] [--mesh 4] \
+      [--model unet|dit|sdxl] [--kernels fused] [--tips adaptive] [--mesh 4] \
       [--ledger] [--continuous --slots 4 --arrival-rate 2.0 --burst 2] \
       [--solver dpm2m,steps=12] [--tiers draft balanced quality] \
       [--replicas 2 --slo-steps 12 --preview-every 2]

@@ -1,9 +1,10 @@
 """Every Pallas kernel of the served path compiles for a TPU v5e.
 
 The TPU compiler runs here on a described (not attached) v5e chip, at the
-real serving widths: the BK-SDM-Tiny UNet's 64x64 level (T=4096, 8 heads
-of 40, patch 64), CLIP's 77 text keys, the 320->2560 GEGLU FFN, and
-DiT-S/2's self-attention (T=256, 6 heads of 64, patch 16).  Interpret-mode
+real serving widths: the BK-SDM UNet's 64x64 level (T=4096, 8 heads of
+40, patch 64), CLIP's 77 text keys, the 320->2560 GEGLU FFN, DiT-S/2's
+self-attention (T=256, 6 heads of 64, patch 16), and SDXL's two attention
+levels (T=4096 with 10 heads of 64, T=1024 with 20).  Interpret-mode
 tests cannot see what the chip's compiler refuses (block tiling, vector
 layouts, integer matmul types); these do, at no chip time.  Each test
 asserts the compiled program contains the kernel (``tpu_custom_call``).
@@ -62,15 +63,19 @@ def _compile(fn, one_chip, *shapes, kernel=None):
 
 F32 = jnp.float32
 UNET_QKV = ((2, 8, 4096, 40), F32)
+SDXL_QKV = ((2, 10, 4096, 64), F32)
 
 
 @pytest.mark.parametrize("qkv,patch", [
     (UNET_QKV, None), (UNET_QKV, 64),
-    (((2, 8, 1024, 80), F32), 32), (((2, 8, 256, 160), F32), 16)],
-    ids=["no_patch", "patch64", "32x32_patch32", "16x16_patch16"])
+    (((2, 8, 1024, 80), F32), 32), (((2, 8, 256, 160), F32), 16),
+    (SDXL_QKV, 64), (((2, 20, 1024, 64), F32), 32)],
+    ids=["no_patch", "patch64", "32x32_patch32", "16x16_patch16",
+         "sdxl_64x64_patch64", "sdxl_32x32_patch32"])
 def test_pssa_attention_compiles_unet_64x64(one_chip, qkv, patch):
-    """Every UNet level (64x64, 32x32, 16x16 latents) at the op's own
-    tiling: a tile the chip's VMEM cannot hold is refused here."""
+    """Every UNet level (64x64, 32x32, 16x16 latents; SDXL's 64x64 and
+    32x32 with heads of 64) at the op's own tiling: a tile the chip's VMEM
+    cannot hold is refused here."""
     fn = functools.partial(pssa_attention, threshold=THRESHOLD, patch=patch,
                            interpret=False)
     _compile(fn, one_chip, qkv, qkv, qkv, kernel="pssa_attention_kernel")
@@ -87,6 +92,13 @@ def test_tips_cross_attention_compiles_clip_text(one_chip):
     text = ((2, 8, 77, 40), F32)
     fn = functools.partial(cross_attention_cas, interpret=False)
     _compile(fn, one_chip, UNET_QKV, text, text,
+             kernel="cross_attention_tips_kernel")
+
+
+def test_tips_cross_attention_compiles_sdxl_text(one_chip):
+    text = ((2, 10, 77, 64), F32)
+    fn = functools.partial(cross_attention_cas, interpret=False)
+    _compile(fn, one_chip, SDXL_QKV, text, text,
              kernel="cross_attention_tips_kernel")
 
 
